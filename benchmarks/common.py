@@ -13,18 +13,24 @@ import time
 
 import jax
 
-# persistent compilation cache: repeat runs skip XLA compiles
-_CACHE = os.environ.get("JAX_COMPILATION_CACHE", "/tmp/jax_bench_cache")
-try:
-    jax.config.update("jax_compilation_cache_dir", _CACHE)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
+from repro.core.tuner import estimator
 
-from repro.core.tuner import estimator  # noqa: E402
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RESULTS_DIR = os.path.join(REPO_ROOT, "results", "bench")
 
-RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results",
-                           "bench")
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache, so repeat runs skip
+    XLA compiles.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads
+    the directory from it and nothing is set here; otherwise the cache
+    lives at the fixed, gitignored ``<repo>/.jax_cache`` (the path is part
+    of the cache key, so a moving directory would never hit)."""
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(REPO_ROOT, ".jax_cache"))
+
+
+enable_compile_cache()
 
 # paper datasets -> laptop-scale stand-ins (true dimensionalities, reduced n:
 # wall-time behaviour tracks the paper only when distance compute dominates)

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -97,10 +98,14 @@ def random_knng_ids(seed: int, n: int, degree: int) -> jax.Array:
     Row u is a prefix-stable pseudo-random sequence: a graph needing a
     smaller initial degree takes a prefix of the same row, so all m Vamana
     initial graphs overlap maximally (deterministic random strategy).
-    Self-loops are redirected to (u+1) mod n.
+    Self-loops are redirected to (u+1) mod n.  Column j is drawn from its
+    own key ``fold_in(key, j)``: one draw of shape (n, degree) is not
+    prefix-stable across degrees under JAX's partitionable threefry.
     """
     key = jax.random.PRNGKey(seed ^ 0x5EED)
-    ids = jax.random.randint(key, (n, degree), 0, n, jnp.int32)
+    ids = jax.vmap(lambda j: jax.random.randint(
+        jax.random.fold_in(key, j), (n,), 0, n, jnp.int32),
+        out_axes=1)(jnp.arange(degree))
     rows = jnp.arange(n, dtype=jnp.int32)[:, None]
     return jnp.where(ids == rows, (ids + 1) % n, ids)
 
@@ -163,6 +168,12 @@ ASSIGNMENTS = ("chunked", "random", "kmeans")
 # build-time only, so the defaults favor determinism and partition quality
 KMEANS_BATCH = 4096
 KMEANS_EPOCHS = 8
+# balanced full-batch Lloyd steps after the mini-batch epochs, the
+# price-update cap inside each, and the k-means++ restarts whose
+# lowest balanced cost wins (see _kmeans_fit / _kmeans_parts)
+KMEANS_REFINE = 10
+KMEANS_PRICE_ITERS = 128
+KMEANS_RESTARTS = 4
 # capacity slack ε: shards may hold up to ⌈n/S · (1+ε)⌉ rows.  A hard
 # ⌈n/S⌉ cap forcibly spills cluster-boundary points into geometrically
 # wrong shards, and each misplaced point is a routing recall hole (its
@@ -234,22 +245,96 @@ class ShardedGraph:
         return self.ids.shape[2]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("num_shards", "kernel", "batch", "epochs"))
-def _kmeans_fit(x: jax.Array, key: jax.Array, *, num_shards: int,
-                kernel: str, batch: int, epochs: int) -> jax.Array:
-    """Mini-batch k-means centroids float32[S, d], one compiled dispatch.
+def _kmeans_init(x: jax.Array, key: jax.Array, num_shards: int,
+                 kernel: str) -> jax.Array:
+    """Greedy k-means++ seeds float32[S, d] (Arthur & Vassilvitskii 2007,
+    with scikit-learn's greedy refinement).
 
-    Sculley-style: each Lloyd step assigns one mini-batch to its nearest
-    centroid under ``kernel`` distance and moves centroids by the
-    count-weighted running mean (per-centroid learning rate 1/seen_count),
-    so early batches move centroids fast and late batches anneal.  Each
-    epoch re-shuffles via ``fold_in(key, epoch)``; the ragged tail of a
-    shuffle is dropped to keep every batch the same static shape.  Pure
+    The first seed is a uniform draw; each later seed is the best of
+    ``2 + ⌊ln S⌋`` candidates drawn with probability proportional to the
+    distance to the nearest seed so far (shifted to be non-negative, so
+    raw-ip distances work too), keeping the candidate that lowers the
+    total nearest-seed distance most.  Spreading the seeds this way keeps
+    the partition from depending on one lucky uniform draw of S rows:
+    two seeds inside one blob would split it and merge two others.
+    """
+    n, d = x.shape
+    trials = 2 + int(math.log(num_shards))
+    first = jax.random.randint(jax.random.fold_in(key, 0), (), 0, n)
+    cents = jnp.zeros((num_shards, d), x.dtype).at[0].set(x[first])
+    dmin = metric_lib.kernel_distance(x, x[first][None, :], kernel)  # (n,)
+    for s in range(1, num_shards):
+        w = dmin - jnp.min(dmin)
+        cand = jax.random.categorical(jax.random.fold_in(key, s),
+                                      jnp.log(w), shape=(trials,))
+        dc = metric_lib.kernel_distance(x[:, None, :], x[cand][None, :, :],
+                                        kernel)                 # (n, trials)
+        pot = jnp.sum(jnp.minimum(dmin[:, None], dc), axis=0)
+        best = jnp.argmin(pot)
+        cents = cents.at[s].set(x[cand[best]])
+        dmin = jnp.minimum(dmin, dc[:, best])
+    return cents
+
+
+def _balance_prices(d: jax.Array, cap: int) -> jax.Array:
+    """Per-shard prices f32[S] so that ``argmin(d + prices)`` fills no
+    shard far beyond ``cap`` rows (a power-diagram assignment).
+
+    An over-full shard raises its price in proportion to its overflow,
+    with a decaying step, until every count fits or KMEANS_PRICE_ITERS
+    steps ran; ``_capacity_round`` enforces the cap exactly afterwards.
+    A priced shard sheds the rows that are cheapest to move — the ones on
+    its border with a neighbour — where a hard cap on raw distances sheds
+    its farthest rows, which need not border anything.
+    """
+    n, num_shards = d.shape
+    scale = jnp.mean(d - jnp.min(d, axis=1, keepdims=True))
+
+    def counts(prices):
+        a = jnp.argmin(d + prices, axis=1)
+        return jax.ops.segment_sum(jnp.ones((n,), jnp.float32), a,
+                                   num_segments=num_shards)
+
+    def cond(st):
+        t, prices = st
+        return (t < KMEANS_PRICE_ITERS) & (jnp.max(counts(prices)) > cap)
+
+    def body(st):
+        t, prices = st
+        over = jnp.maximum(counts(prices) - cap, 0.0)
+        step = 0.5 * scale * num_shards / n / (1.0 + t) ** 0.3
+        return t + 1, prices + step * over
+
+    _, prices = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), jnp.zeros((num_shards,), jnp.float32)))
+    return prices
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_shards", "kernel", "batch", "epochs", "cap"))
+def _kmeans_fit(x: jax.Array, key: jax.Array, *, num_shards: int,
+                kernel: str, batch: int, epochs: int, cap: int):
+    """Balanced k-means: (centroids f32[S, d], prices f32[S], cost f32[]),
+    one compiled dispatch.
+
+    Seeds by greedy k-means++ (``_kmeans_init``).  Sculley-style Lloyd
+    steps: each assigns one mini-batch to its nearest centroid under
+    ``kernel`` distance and moves centroids by the count-weighted running
+    mean (per-centroid learning rate 1/seen_count), so early batches move
+    centroids fast and late batches anneal.  Each epoch re-shuffles via
+    ``fold_in(key, epoch)``; the ragged tail of a shuffle is dropped to
+    keep every batch the same static shape.  ``KMEANS_REFINE`` full-batch
+    Lloyd steps then settle the centroids under the capacity: each prices
+    the shards (``_balance_prices``; l2 kernel only), assigns every row
+    to its cheapest shard and moves each centroid to its members' mean.  ``cost`` is the
+    summed distance of each row to the centroid of its priced shard — the
+    balanced objective ``_kmeans_parts`` compares restarts by.  Pure
     function of (x, key) — partition determinism inherits from here.
     """
     n = x.shape[0]
-    cents = x[jax.random.choice(key, n, (num_shards,), replace=False)]
+    init_key, key = jax.random.split(key)
+    cents = _kmeans_init(x, init_key, num_shards, kernel)
     counts = jnp.ones((num_shards,), jnp.float32)
     nb = max(n // batch, 1)
 
@@ -272,8 +357,29 @@ def _kmeans_fit(x: jax.Array, key: jax.Array, *, num_shards: int,
                                 perm[:nb * batch].reshape(nb, batch))
         return carry
 
+    def priced(cents):
+        d = metric_lib.kernel_distance(x[:, None, :], cents[None, :, :],
+                                       kernel)                   # (n, S)
+        # ip routing ranks shards by <q, c>, which a query's scale does not
+        # change; an additive price does, so a priced ip partition puts
+        # rows where the router would not look for them: ip stays unpriced
+        prices = (_balance_prices(d, cap) if kernel == "l2"
+                  else jnp.zeros((num_shards,), jnp.float32))
+        return d, prices, jnp.argmin(d + prices, axis=1)
+
+    def lloyd(_, cents):
+        _, _, a = priced(cents)
+        cnt = jax.ops.segment_sum(jnp.ones((n,), jnp.float32), a,
+                                  num_segments=num_shards)
+        sx = jax.ops.segment_sum(x, a, num_segments=num_shards)
+        return jnp.where(cnt[:, None] > 0,
+                         sx / jnp.maximum(cnt, 1.0)[:, None], cents)
+
     cents, _ = jax.lax.fori_loop(0, epochs, epoch, (cents, counts))
-    return cents
+    cents = jax.lax.fori_loop(0, KMEANS_REFINE, lloyd, cents)
+    d, prices, a = priced(cents)
+    cost = jnp.sum(jnp.take_along_axis(d, a[:, None], axis=1))
+    return cents, prices, cost
 
 
 def _capacity_round(dist, cap: int):
@@ -328,23 +434,31 @@ def _capacity_round(dist, cap: int):
 
 
 def _kmeans_parts(n: int, num_shards: int, data, metric: str, seed: int):
-    """(per-shard global-id arrays, Lloyd centroids f32[S, d]) for "kmeans".
+    """(per-shard global-id arrays, centroids f32[S, d]) for "kmeans".
 
-    The centroids returned are the CLUSTERING MODEL's, not the rounded
-    members' means: capacity rounding spills boundary points, and scoring
-    queries against post-spill member means ranks shards differently from
-    the statistic the placement optimized — measured as routing recall
-    holes on cluster boundaries (DESIGN.md §13).
+    Runs KMEANS_RESTARTS balanced k-means fits from different k-means++
+    seeds and keeps the one with the lowest balanced cost: the plain
+    k-means optimum can be badly unbalanced (three blobs in one cluster,
+    one in another), and any balanced placement of it spills whole
+    cluster borders into the wrong shard — routing recall holes.  The
+    winner's priced distances are then rounded to the hard cap by
+    ``_capacity_round``, which moves few rows because the prices already
+    balanced the shards.  The centroids returned are the fit's (the
+    statistic the placement optimized), which routing scores queries
+    against (DESIGN.md §13).
     """
     import numpy as np
     met = metric_lib.resolve(metric)
     x = met.prepare(jnp.asarray(data, jnp.float32))
-    cents = _kmeans_fit(
-        x, jax.random.PRNGKey(seed ^ 0xC3A7), num_shards=num_shards,
-        kernel=met.kernel, batch=min(KMEANS_BATCH, n), epochs=KMEANS_EPOCHS)
-    d = metric_lib.kernel_distance(x[:, None, :], cents[None, :, :],
-                                   met.kernel)
     cap = int(np.ceil(n / num_shards * (1.0 + KMEANS_CAP_SLACK)))
+    key = jax.random.PRNGKey(seed ^ 0xC3A7)
+    fits = [_kmeans_fit(x, jax.random.fold_in(key, r), num_shards=num_shards,
+                        kernel=met.kernel, batch=min(KMEANS_BATCH, n),
+                        epochs=KMEANS_EPOCHS, cap=cap)
+            for r in range(KMEANS_RESTARTS)]
+    cents, prices, _ = min(fits, key=lambda f: float(f[2]))
+    d = metric_lib.kernel_distance(x[:, None, :], cents[None, :, :],
+                                   met.kernel) + prices[None, :]
     assign = _capacity_round(np.asarray(d), cap)
     return [np.flatnonzero(assign == s).astype(np.int32)
             for s in range(num_shards)], cents

@@ -53,7 +53,11 @@ def pairwise_candidate_dist(data: jax.Array, cand_ids: jax.Array,
     """
     met = metric_lib.resolve(metric)
     c = met.prepare(data[jnp.maximum(cand_ids, 0)].astype(jnp.float32))
-    cross = jnp.einsum("bld,bkd->blk", c, c)                    # (b, L, L)
+    # HIGHEST: the l2 form below cancels norms against 2·cross, and the
+    # alpha rule compares the result with fp32 candidate distances; a
+    # default-precision dot on the TPU MXU rounds its operands to bf16.
+    cross = jnp.einsum("bld,bkd->blk", c, c,
+                       precision=jax.lax.Precision.HIGHEST)       # (b, L, L)
     if met.kernel == "ip":
         # Clamp at 0: raw-ip pair distances can be negative, which would
         # invert the alpha rule (larger alpha dominating MORE) and break

@@ -69,7 +69,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import graph as graph_lib
@@ -433,7 +432,10 @@ def beam_search(
     for i in range(m):
         ep = entry[:, i]
         ep_safe = jnp.maximum(ep, 0)
-        ok = (ep != INVALID) & (ep != query_ids) & row_mask
+        # a build row whose node IS the entry (the medoid's own insertion)
+        # seeds its pool with itself too, so the search expands the
+        # entry's neighbourhood; the id is dropped from the final pool
+        ok = (ep != INVALID) & row_mask
         d0 = _gathered_distance(data, ep_safe[:, None], queries,
                                 metric)[:, 0]
         if share_cache:
@@ -485,6 +487,20 @@ def beam_search(
     # Mask out slots beyond each graph's ef (they are not part of C(u)).
     pool_ids = jnp.where(slot_mask[None], pool_ids, INVALID)
     pool_dist = jnp.where(slot_mask[None], pool_dist, jnp.inf)
+    # C(u) excludes u: drop the query's own id (only a self-entry seed can
+    # have pooled it) and shift the slots after it up by one
+    is_self = ((pool_ids == query_ids[:, None, None])
+               & (query_ids != INVALID)[:, None, None])
+    at = jnp.where(jnp.any(is_self, axis=-1), jnp.argmax(is_self, axis=-1),
+                   ef_max)[..., None]
+    src = jnp.where(jnp.arange(ef_max) < at, jnp.arange(ef_max),
+                    jnp.arange(1, ef_max + 1))
+    pool_ids = jnp.take_along_axis(
+        jnp.pad(pool_ids, ((0, 0), (0, 0), (0, 1)),
+                constant_values=INVALID), src, axis=-1)
+    pool_dist = jnp.take_along_axis(
+        jnp.pad(pool_dist, ((0, 0), (0, 0), (0, 1)),
+                constant_values=jnp.inf), src, axis=-1)
     return SearchResult(pool_ids, pool_dist, n_fresh, n_comp, hops,
                         cache_d, cache_has)
 
@@ -672,12 +688,12 @@ def _sharded_search_fn(mesh, *, k, ef, max_hops, metric, visited_impl,
         visited_impl=visited_impl, hash_slots=hash_slots,
         expand_width=expand_width)
     n_quant = 3 if quantize else 0
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("shard"), P("shard"), P("shard"), P("shard"),
                   P("shard"), P(), P()) + (P("shard"),) * n_quant,
         out_specs=(P("shard"), P("shard"), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def run(graph_ids, data, global_ids, entries, shard_mask, queries,
@@ -798,11 +814,11 @@ def _routed_search_fn(mesh, *, k, ef, max_hops, metric, visited_impl,
         visited_impl=visited_impl, hash_slots=hash_slots,
         expand_width=expand_width)
     n_quant = 3 if quantize else 0
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("shard"),) * (6 + n_quant),
         out_specs=(P("shard"), P("shard"), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def run(graph_ids, data, global_ids, entries, queries, q_index, q_mask,

@@ -5,6 +5,12 @@ signal variance, log noise) fit by Adam on the exact log marginal likelihood.
 Inputs live in the unit hypercube (ParamSpace.encode); targets are
 standardized internally.  Everything is f64-free and Cholesky-based with a
 jitter floor, sized for the O(100) observations a tuning run produces.
+
+``fit``, ``predict`` and ``sample`` run at ``highest`` matmul precision:
+an f32 dot at default precision on the TPU's MXU rounds its operands to
+bf16, which moves the posterior mean by ~6e-2 and leaves the predictive
+covariance without a Cholesky factor (measured on a v5e; the CPU is
+unaffected).
 """
 from __future__ import annotations
 
@@ -74,6 +80,7 @@ def _fit_params(x, y, log_ls0, log_sf0, log_sn0, *, steps: int = 80):
     return params
 
 
+@jax.default_matmul_precision("highest")
 def fit(x: jax.Array, y: jax.Array, *, steps: int = 80) -> GPState:
     x = jnp.asarray(x, jnp.float32)
     y = jnp.asarray(y, jnp.float32)
@@ -92,6 +99,7 @@ def fit(x: jax.Array, y: jax.Array, *, steps: int = 80) -> GPState:
                    y_mean=y_mean, y_std=y_std, chol=chol, alpha=alpha)
 
 
+@jax.default_matmul_precision("highest")
 def predict(gp: GPState, xq: jax.Array, *, full_cov: bool = False
             ) -> tuple[jax.Array, jax.Array]:
     """Posterior mean (q,) and variance (q,) — or covariance (q, q)."""
@@ -109,6 +117,7 @@ def predict(gp: GPState, xq: jax.Array, *, full_cov: bool = False
     return mean, var
 
 
+@jax.default_matmul_precision("highest")
 def sample(gp: GPState, xq: jax.Array, key: jax.Array, n_samples: int
            ) -> jax.Array:
     """(n_samples, q) joint posterior samples (full covariance)."""

@@ -20,6 +20,9 @@ blocks:
 ``d`` stays un-blocked (PG datasets have d <= 1024; a 128x1024 f32 tile is
 512 KiB, well within the ~16 MiB VMEM budget at the default block sizes).
 Block sizes default to MXU-aligned 128x128; the ops.py wrapper pads inputs.
+The cross term asks for ``Precision.HIGHEST``: the l2 form cancels
+||q||^2 + ||x||^2 against 2 q.x, so bf16-rounded MXU operands would move
+distances by far more than fp32 rounding (kernels/gather_distance.py).
 """
 from __future__ import annotations
 
@@ -41,6 +44,7 @@ def _dist_kernel(q_ref, x_ref, o_ref, *, kernel: str):
     cross = jax.lax.dot_general(
         q, x,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     if kernel == "ip":
@@ -103,6 +107,7 @@ def _dist_sq8_kernel(qs_ref, qn_ref, c_ref, cn_ref, o_ref, *, kernel: str):
     cross = jax.lax.dot_general(
         qs, c,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     if kernel == "ip":

@@ -84,12 +84,11 @@ def gather_distance(u, c, cached=None, mask=None,
         mask = jnp.ones((b, k), dtype=bool)
     if not (_use_pallas() or _use_interpret()):
         return ref.gather_distance_ref(u, c, cached, mask, met.kernel)
-    bk = min(_gd.DEFAULT_BK, max(8, k))
-    cp = _pad_to(c, 1, bk)
+    bk = _gd.gather_block(k, c.dtype)
+    cp = _pad_to(_pad_to(c, 1, bk), 2, 128)
     cachedp = _pad_to(cached, 1, bk)
     maskp = _pad_to(mask, 1, bk, value=True)
     up = _pad_to(u, 1, 128)
-    cp = _pad_to(cp, 2, 128)
     out = _gd.gather_distance(up, cp, cachedp, maskp, kernel=met.kernel,
                               bk=bk, interpret=_use_interpret())
     return out[:, :k]
@@ -149,7 +148,7 @@ def gather_distance_q(u, codes, scale, cnorms, cached=None, mask=None,
     u = u.astype(jnp.float32)
     qs = u * scale[None, :]
     qn = jnp.sum(u * u, axis=-1, keepdims=True)
-    bk = min(_gd.DEFAULT_BK, max(8, k))
+    bk = _gd.gather_block(k, codes.dtype)
     cp = _pad_to(_pad_to(codes, 1, bk), 2, 128)
     cnp = _pad_to(cnorms, 1, bk)
     cachedp = _pad_to(cached, 1, bk)

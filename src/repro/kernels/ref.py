@@ -81,8 +81,10 @@ def pairwise_distance_sq8_ref(q: jax.Array, codes: jax.Array,
     The quantized forms' semantic oracle AND the CPU dispatch path
     (ops.py), mirroring ``pairwise_distance_ref``.  ADC formulation
     (DESIGN.md §16): the fp32 query is pre-scaled once, the cross term is
-    one fp32 dot against the upcast codes — identical operand shapes and
-    contraction to the Pallas kernel so interpret mode bit-matches.
+    one fp32 dot against the upcast codes, contracted over d zero-padded to
+    the kernel's 128 lanes — the Pallas kernel's contraction, so interpret
+    mode bit-matches (an unpadded dot may group the d-sum differently on
+    XLA CPU: one ulp).
 
     Args:
       q: (nq, d) fp32 queries in prepared space.
@@ -94,9 +96,10 @@ def pairwise_distance_sq8_ref(q: jax.Array, codes: jax.Array,
       (nq, nx) float32 distances to the dequantized corpus.
     """
     q = q.astype(jnp.float32)
-    qs = q * scale[None, :]
+    lanes = ((0, 0), (0, -q.shape[1] % 128))
+    qs = jnp.pad(q * scale[None, :], lanes)
     cross = jax.lax.dot_general(
-        qs, codes.astype(jnp.float32),
+        qs, jnp.pad(codes.astype(jnp.float32), lanes),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                  # (nq, nx)
     if kernel == "ip":

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import eval as evallib
-from repro.core import hnsw, nsg, vamana
+from repro.core import graph, hnsw, nsg, vamana
 
 
 @pytest.fixture(scope="module")
@@ -31,6 +31,54 @@ def test_multi_equals_single_fast():
         np.testing.assert_array_equal(
             np.asarray(multi.g.ids[i])[:, :p.M],
             np.asarray(single.g.ids[0])[:, :p.M])
+
+
+def test_random_knng_prefix_stable():
+    """A smaller initial degree takes a prefix of the same rows."""
+    wide = np.asarray(graph.random_knng_ids(3, 100, 16))
+    np.testing.assert_array_equal(
+        wide[:, :8], np.asarray(graph.random_knng_ids(3, 100, 8)))
+    assert not np.any(wide == np.arange(100)[:, None])
+
+
+def test_multi_equals_single_across_degree_buckets():
+    """A group member whose M pads to a smaller degree bucket alone (M=8
+    -> 8) than in its group (-> 16, for M=12) still builds the same graph:
+    the tuner's grouped estimates then equal its single ones."""
+    r = np.random.default_rng(22)
+    data = jnp.asarray(r.normal(size=(250, 8)), jnp.float32)
+    ps = [vamana.VamanaParams(L=16, M=8, alpha=1.1),
+          vamana.VamanaParams(L=20, M=12, alpha=1.3)]
+    multi = vamana.build_multi_vamana(data, ps, seed=3, batch_size=128)
+    for i, p in enumerate(ps):
+        single = vamana.build_vamana(data, p, seed=3, batch_size=128)
+        np.testing.assert_array_equal(
+            np.asarray(multi.g.ids[i])[:, :p.M],
+            np.asarray(single.g.ids[0])[:, :p.M])
+
+
+@pytest.mark.parametrize("build_impl", ["per_batch", "fused"])
+def test_entry_inserted_last_keeps_graph_connected(ds, build_impl):
+    """The entry (medoid) inserted in the last batch: its own insertion
+    searches from itself, and that search must expand its neighbourhood.
+    An empty pool there wiped the entry's out-edges, leaving only the
+    reverse edges of later rows — at scale, every row inserted before the
+    entry was cut off from it."""
+    data, _, _ = ds
+    n, batch = data.shape[0], 128
+    ep = int(graph.medoid(data))
+    order = np.r_[np.delete(np.arange(n), ep), ep]
+    x = data[order]
+    res = vamana.build_vamana(x, vamana.VamanaParams(32, 12, 1.2),
+                              batch_size=batch, build_impl=build_impl)
+    assert res.entry == n - 1
+    out = np.asarray(res.g.ids[0])[res.entry]
+    out = out[out >= 0]
+    d = np.sum((np.asarray(x) - np.asarray(x)[res.entry]) ** 2, axis=1)
+    d[res.entry] = np.inf
+    assert res.entry not in out
+    assert int(np.argmin(d)) in out          # its nearest neighbour
+    assert np.any(out < (n - 1) // batch * batch)   # rows of earlier batches
 
 
 @pytest.mark.slow
