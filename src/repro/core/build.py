@@ -14,7 +14,7 @@ jitted function (DESIGN.md §12):
 
   ``insert_batch``      the Vamana/HNSW-shaped step (search the evolving
                         graph, prune, commit) — a single dispatch per batch,
-                        counters returned as an int32[4] device row.
+                        counters returned as an int32[7] device row.
   ``nsg_insert_batch``  the NSG-shaped step (search a *static* KNNG, merge
                         each node's own KNNG row into the candidates, prune,
                         commit) — same single-dispatch contract.
@@ -49,7 +49,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import commit, counters as counters_lib, prune, search
+from repro.core import commit, counters as counters_lib, obs, prune, search
 from repro.core.graph import INVALID
 
 BUILD_IMPLS = ("per_batch", "fused")
@@ -72,7 +72,7 @@ def _insert_step(graph_ids, graph_dist, data, u, row_mask, queries, L, M,
     one traceable function — counters come back as device scalars instead
     of Python-int mutations, everything else is unchanged (bit-identity by
     construction).  Returns ``(new_ids, new_dist, ctr_row, top_ids,
-    cache_d, cache_has)`` where ``ctr_row`` is the int32[4] CounterTape row
+    cache_d, cache_has)`` where ``ctr_row`` is the int32[7] CounterTape row
     and ``top_ids`` is each (query, graph)'s closest pool entry (HNSW's
     next-layer entry points; Vamana ignores it).
     """
@@ -86,14 +86,16 @@ def _insert_step(graph_ids, graph_dist, data, u, row_mask, queries, L, M,
     cand_ids = jnp.transpose(res.pool_ids, (1, 0, 2))     # (m, b, ef_max)
     cand_dist = jnp.transpose(res.pool_dist, (1, 0, 2))
     valid = cand_ids != INVALID
-    pruned, nb, nc = prune.multi_prune(
-        data, cand_ids, cand_dist, valid, M, alpha,
-        m_max=m_max, use_epo=use_epo, metric=metric)
+    with jax.named_scope(obs.PRUNE):
+        pruned, nb, nc = prune.multi_prune(
+            data, cand_ids, cand_dist, valid, M, alpha,
+            m_max=m_max, use_epo=use_epo, metric=metric)
 
-    new_ids, new_dist, rev_checks = commit.commit_group(
-        data, graph_ids, graph_dist, u, pruned, row_mask, M, alpha,
-        k_in=k_in, m_max=m_max, metric=metric)
-    ctr_row = counters_lib.step_row(res.n_fresh, res.n_computed,
+    with jax.named_scope(obs.COMMIT):
+        new_ids, new_dist, rev_checks = commit.commit_group(
+            data, graph_ids, graph_dist, u, pruned, row_mask, M, alpha,
+            k_in=k_in, m_max=m_max, metric=metric)
+    ctr_row = counters_lib.step_row(res, row_mask, expand_width,
                                     nb + rev_checks, nc + rev_checks)
     return (new_ids, new_dist, ctr_row, res.pool_ids[:, :, 0],
             res.cache_d, res.cache_has)
@@ -151,14 +153,16 @@ def _nsg_step(search_graph_ids, graph_ids, graph_dist, knn_ids, knn_dist,
     cand_ids = jnp.where(dup, INVALID, cand_ids)
     cand_dist = jnp.where(dup, jnp.inf, cand_dist)
     valid = cand_ids != INVALID
-    pruned, nb, nc = prune.multi_prune(
-        data, cand_ids, cand_dist, valid, M, alpha,
-        m_max=m_max, use_epo=use_epo, metric=metric)
+    with jax.named_scope(obs.PRUNE):
+        pruned, nb, nc = prune.multi_prune(
+            data, cand_ids, cand_dist, valid, M, alpha,
+            m_max=m_max, use_epo=use_epo, metric=metric)
 
-    new_ids, new_dist, rev_checks = commit.commit_group(
-        data, graph_ids, graph_dist, u, pruned, row_mask, M, alpha,
-        k_in=k_in, m_max=m_max, metric=metric)
-    ctr_row = counters_lib.step_row(res.n_fresh, res.n_computed,
+    with jax.named_scope(obs.COMMIT):
+        new_ids, new_dist, rev_checks = commit.commit_group(
+            data, graph_ids, graph_dist, u, pruned, row_mask, M, alpha,
+            k_in=k_in, m_max=m_max, metric=metric)
+    ctr_row = counters_lib.step_row(res, row_mask, expand_width,
                                     nb + rev_checks, nc + rev_checks)
     return new_ids, new_dist, ctr_row
 
@@ -186,14 +190,14 @@ def fused_vamana_pass(graph_ids, graph_dist, data, L, M, alpha, ep, *,
     queries gathered at ``min(u, n-1)`` — then runs ``_insert_step``, so
     the final graphs and the per-batch counter rows are bit-identical to
     ``build_impl="per_batch"``.  Counter rows land in a preallocated
-    int32[n_batches, 4] log (no int64 carry needed: the host sums the
+    int32[n_batches, 7] log (no int64 carry needed: the host sums the
     fetched log in int64), returned alongside the graphs for one
     ``CounterTape.log_many`` + end-of-build sync.
     """
     n = data.shape[0]
     m = graph_ids.shape[0]
     n_batches = -(-n // batch_size)
-    log = jnp.zeros((n_batches, 4), jnp.int32)
+    log = jnp.zeros((n_batches, len(counters_lib.TAPE_FIELDS)), jnp.int32)
 
     def body(t, carry):
         ids, dist, log = carry

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import knng, search
+from repro.core import knng, obs, search
 from repro.core.graph import INVALID, MultiGraph
 
 
@@ -70,16 +70,20 @@ def evaluate_search_fn(
     points = []
     nq = queries.shape[0]
     for ef in ef_grid:
-        res = search_fn(queries, ef)
-        jax.block_until_ready(res.pool_ids)
-        t0 = time.perf_counter()
-        for _ in range(timing_reps):
-            r2 = search_fn(queries, ef)
-            jax.block_until_ready(r2.pool_ids)
-        dt = (time.perf_counter() - t0) / timing_reps
-        rec = recall_at_k(res.pool_ids[:, :k], gt_ids)
-        points.append(EvalPoint(ef=ef, recall=rec, qps=nq / max(dt, 1e-9),
-                                n_dist=int(res.n_computed)))
+        with obs.span(obs.EVAL_POINT):
+            with obs.span(obs.RECALL_SEARCH):
+                res = search_fn(queries, ef)
+                jax.block_until_ready(res.pool_ids)
+            with obs.span(obs.TIMED_SEARCH):
+                t0 = time.perf_counter()
+                for _ in range(timing_reps):
+                    r2 = search_fn(queries, ef)
+                    jax.block_until_ready(r2.pool_ids)
+                dt = (time.perf_counter() - t0) / timing_reps
+            rec = recall_at_k(res.pool_ids[:, :k], gt_ids)
+            points.append(EvalPoint(ef=ef, recall=rec,
+                                    qps=nq / max(dt, 1e-9),
+                                    n_dist=int(res.n_computed)))
     return points
 
 

@@ -27,7 +27,7 @@ import numpy as np
 from repro.core import build as build_lib
 from repro.core import commit, graph, hashset, prune, search
 from repro.core import metric as metric_lib
-from repro.core.counters import BuildCounters, CounterTape
+from repro.core.counters import BuildCounters, CounterTape, step_row
 from repro.core.graph import INVALID
 
 
@@ -142,17 +142,17 @@ def build_multi_hnsw(
             ins_np = row_mask_np & (lvl_np >= layer)
             next_entry = entry
             if desc_np.any():   # greedy descent, Alg. 5 l.10-11
+                desc_mask = jnp.array(desc_np)
                 res = search.beam_search(
-                    lids[layer], data, queries, qids, jnp.array(desc_np),
+                    lids[layer], data, queries, qids, desc_mask,
                     ones, entry, cache_d, cache_has,
                     ef_max=1, max_hops=hops, share_cache=use_eso,
                     metric=kform, visited_impl=visited_impl)
                 cache_d, cache_has = res.cache_d, res.cache_has
-                tape.log(res.n_fresh, res.n_computed, 0, 0)
+                tape.log(step_row(res, desc_mask, 1))
                 got = res.pool_ids[:, :, 0]
                 next_entry = jnp.where(
-                    jnp.array(desc_np)[:, None] & (got != INVALID),
-                    got, next_entry)
+                    desc_mask[:, None] & (got != INVALID), got, next_entry)
             if ins_np.any():    # search + mPrune + commit, Alg. 5 l.13-19
                 ins_mask = jnp.array(ins_np)
                 if build_impl == "fused":
@@ -163,7 +163,7 @@ def build_multi_hnsw(
                             lids[layer], ldist[layer], data, u, ins_mask,
                             queries, efc, M, alpha1, entry,
                             cache_d, cache_has, **step_kw))
-                    tape.log_row(row)
+                    tape.log(row)
                 else:
                     res = search.beam_search(
                         lids[layer], data, queries, qids, ins_mask,
@@ -184,8 +184,9 @@ def build_multi_hnsw(
                         data, lids[layer], ldist[layer], u, pruned,
                         ins_mask, M, alpha1, k_in=k_in, m_max=M_max,
                         metric=kform)
-                    tape.log(res.n_fresh, res.n_computed,
-                             nb + rev_checks, nc + rev_checks)
+                    tape.log(step_row(
+                        res, ins_mask, expand_width,
+                        nb + rev_checks, nc + rev_checks))
                 next_entry = jnp.where(
                     ins_mask[:, None] & (got != INVALID), got, next_entry)
                 lids = lids.at[layer].set(nl)

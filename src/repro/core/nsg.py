@@ -26,7 +26,7 @@ import numpy as np
 from repro.core import build as build_lib
 from repro.core import commit, graph, knng, prune, search
 from repro.core import metric as metric_lib
-from repro.core.counters import BuildCounters, CounterTape
+from repro.core.counters import BuildCounters, CounterTape, step_row
 from repro.core.graph import INVALID, MultiGraph
 from repro.kernels import ops
 
@@ -120,7 +120,7 @@ def build_multi_nsg(
                 expand_width=expand_width, k_in=k_in, m_max=M_max,
                 k_max=K_max)
             g = MultiGraph(ids=new_ids, dist=new_dist)
-            tape.log_row(row)
+            tape.log(row)
             continue
 
         res = search.beam_search(
@@ -163,8 +163,8 @@ def build_multi_nsg(
             data, g.ids, g.dist, u, pruned, row_mask, M, alpha1,
             k_in=k_in, m_max=M_max, metric=kform)
         g = MultiGraph(ids=new_ids, dist=new_dist)
-        tape.log(res.n_fresh, res.n_computed,
-                 nb + rev_checks, nc + rev_checks)
+        tape.log(step_row(res, row_mask, expand_width,
+                          nb + rev_checks, nc + rev_checks))
 
     tape.drain_into(ctr)          # the main pass's ONE counter host sync
 

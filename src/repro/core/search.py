@@ -74,6 +74,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import graph as graph_lib
 from repro.core import hashset
 from repro.core import metric as metric_lib
+from repro.core import obs
 from repro.core.graph import INVALID
 from repro.distributed import sharding as sharding_lib
 from repro.kernels import ops
@@ -89,6 +90,8 @@ class SearchResult(NamedTuple):
     hops: jax.Array        # int32[]
     cache_d: jax.Array     # float32[b, n] V_delta (or [b, 1] dummy)
     cache_has: jax.Array   # bool[b, n] dense | int32[b, S] hash key table
+    # int32[] pool slots expanded over the live rows (beam_search only)
+    expansions: jax.Array | None = None
 
 
 def fresh_cache(b: int, n: int, share_cache: bool,
@@ -216,13 +219,14 @@ def _gathered_distance(data, flat_ids, queries, metric):
     the quantized form (ops.gather_distance_q) — same (b, k) f32 output,
     priced against the dequantized corpus (DESIGN.md §16).
     """
-    if isinstance(data, metric_lib.QuantizedData):
-        ccodes = data.codes[flat_ids]                    # (b, k, d) int8
-        cn = data.norms[flat_ids]                        # (b, k)
-        return ops.gather_distance_q(queries, ccodes, data.scale, cn,
-                                     metric=metric)
-    cvec = data[flat_ids]                                # (b, k, d)
-    return ops.gather_distance(queries, cvec, metric=metric)
+    with jax.named_scope(obs.DISTANCE):
+        if isinstance(data, metric_lib.QuantizedData):
+            ccodes = data.codes[flat_ids]                # (b, k, d) int8
+            cn = data.norms[flat_ids]                    # (b, k)
+            return ops.gather_distance_q(queries, ccodes, data.scale, cn,
+                                         metric=metric)
+        cvec = data[flat_ids]                            # (b, k, d)
+        return ops.gather_distance(queries, cvec, metric=metric)
 
 
 def rerank_pool(queries, data, pool_ids, *, metric):
@@ -274,91 +278,97 @@ def _expand_all_graphs(graph_ids, data, queries, query_ids, row_mask,
     mrange = jnp.arange(m)
     hash_visited = visited.dtype != jnp.bool_
 
-    unexp = (pool_ids != INVALID) & (~expanded) & slot_mask[None]
-    # W closest unexpanded slots: the pool is sorted ascending, so these are
-    # the first W unexpanded slot positions (top_k of the negated position;
-    # position ef_max = "no slot" sentinel).
-    slot_pos = jnp.where(unexp, jnp.arange(ef_max), ef_max)
-    neg_sel, _ = jax.lax.top_k(-slot_pos, width)
-    sel = -neg_sel                                               # (b, m, W)
-    act = (sel < ef_max) & row_mask[:, None, None]               # (b, m, W)
-    sel_safe = jnp.minimum(sel, ef_max - 1)
-    u = jnp.take_along_axis(pool_ids, sel_safe, axis=-1)         # (b, m, W)
-    u_safe = jnp.where(act, jnp.maximum(u, 0), 0)
-    expanded = expanded.at[brange[:, None, None], mrange[None, :, None],
-                           jnp.where(act, sel_safe, ef_max)].set(
-        True, mode="drop")
+    with jax.named_scope(obs.FRONTIER):
+        unexp = (pool_ids != INVALID) & (~expanded) & slot_mask[None]
+        # W closest unexpanded slots: the pool is sorted ascending, so these
+        # are the first W unexpanded slot positions (top_k of the negated
+        # position; position ef_max = "no slot" sentinel).
+        slot_pos = jnp.where(unexp, jnp.arange(ef_max), ef_max)
+        neg_sel, _ = jax.lax.top_k(-slot_pos, width)
+        sel = -neg_sel                                           # (b, m, W)
+        act = (sel < ef_max) & row_mask[:, None, None]           # (b, m, W)
+        sel_safe = jnp.minimum(sel, ef_max - 1)
+        u = jnp.take_along_axis(pool_ids, sel_safe, axis=-1)     # (b, m, W)
+        u_safe = jnp.where(act, jnp.maximum(u, 0), 0)
+        expanded = expanded.at[brange[:, None, None], mrange[None, :, None],
+                               jnp.where(act, sel_safe, ef_max)].set(
+            True, mode="drop")
 
-    nbrs = graph_ids[mrange[None, :, None], u_safe]           # (b, m, W, Mx)
-    nbrs = nbrs.reshape(b, m, kx)
-    nbrs_safe = jnp.maximum(nbrs, 0)
-    # same-id duplicates within one (query, graph) hop count/insert once
-    # (small W·Mx: a triangular compare beats a sort here).  Compared on
-    # the raw ids: clamped INVALID lanes would alias node 0 and discard a
-    # genuine id-0 candidate arriving after padding.
-    eq = nbrs[..., :, None] == nbrs[..., None, :]
-    tri = jnp.tril(jnp.ones((kx, kx), bool), k=-1)
-    dup = jnp.any(eq & tri[None, None], axis=-1)
-    act_flat = jnp.repeat(act, mx, axis=-1)                      # (b, m, kx)
-    prelim = ((nbrs != INVALID) & act_flat
-              & (nbrs != query_ids[:, None, None]) & ~dup)
-    if hash_visited:
-        visited, vis, _ = hashset.lookup_insert(visited, nbrs_safe, prelim)
-        # Overflow guard: a dropped insert can re-propose a node that is
-        # already pooled; dense mode can't (pool membership implies a set
-        # visit bit), so only hash mode pays this compare (DESIGN.md §9).
-        in_pool = jnp.any(
-            nbrs_safe[..., :, None] == pool_ids[..., None, :], axis=-1)
-        valid = prelim & ~vis & ~in_pool
-    else:
-        vis = visited[brange[:, None, None], mrange[None, :, None],
-                      nbrs_safe]
-        valid = prelim & ~vis
+        nbrs = graph_ids[mrange[None, :, None], u_safe]      # (b, m, W, Mx)
+        nbrs = nbrs.reshape(b, m, kx)
+        nbrs_safe = jnp.maximum(nbrs, 0)
+        # same-id duplicates within one (query, graph) hop count/insert once
+        # (small W·Mx: a triangular compare beats a sort here).  Compared on
+        # the raw ids: clamped INVALID lanes would alias node 0 and discard a
+        # genuine id-0 candidate arriving after padding.
+        eq = nbrs[..., :, None] == nbrs[..., None, :]
+        tri = jnp.tril(jnp.ones((kx, kx), bool), k=-1)
+        dup = jnp.any(eq & tri[None, None], axis=-1)
+        act_flat = jnp.repeat(act, mx, axis=-1)                  # (b, m, kx)
+        prelim = ((nbrs != INVALID) & act_flat
+                  & (nbrs != query_ids[:, None, None]) & ~dup)
+    with jax.named_scope(obs.VISIT):
+        if hash_visited:
+            visited, vis, _ = hashset.lookup_insert(visited, nbrs_safe, prelim)
+            # Overflow guard: a dropped insert can re-propose a node that is
+            # already pooled; dense mode can't (pool membership implies a set
+            # visit bit), so only hash mode pays this compare (DESIGN.md §9).
+            in_pool = jnp.any(
+                nbrs_safe[..., :, None] == pool_ids[..., None, :], axis=-1)
+            valid = prelim & ~vis & ~in_pool
+        else:
+            vis = visited[brange[:, None, None], mrange[None, :, None],
+                          nbrs_safe]
+            valid = prelim & ~vis
 
-    flat_ids = nbrs_safe.reshape(b, m * kx)
-    flat_valid = valid.reshape(b, m * kx)
-    if share_cache and m > 1:
-        first = _first_occurrence(
-            jnp.where(flat_valid, flat_ids, n + jnp.arange(m * kx)[None, :]),
-            n)                                                    # (b, m*kx)
-        first = first & flat_valid
-    else:
-        first = flat_valid
+        flat_ids = nbrs_safe.reshape(b, m * kx)
+        flat_valid = valid.reshape(b, m * kx)
+        if share_cache and m > 1:
+            first = _first_occurrence(
+                jnp.where(flat_valid, flat_ids,
+                          n + jnp.arange(m * kx)[None, :]),
+                n)                                                # (b, m*kx)
+            first = first & flat_valid
+        else:
+            first = flat_valid
 
     dists = _gathered_distance(data, flat_ids, queries, metric)
-    if share_cache:
-        # V_delta's domain is exactly the union of per-graph visit sets, so
-        # only membership is tracked; the values come from the batched kernel
-        # either way (lockstep hardware computes the tile regardless —
-        # DESIGN.md §3, §Perf iteration 5). #dist counters stay exact in
-        # dense mode; hash mode upper-bounds them under overflow (§9).
-        if cache_has.dtype != jnp.bool_:
-            # first-occurrence lanes only: keys distinct within each row.
-            cache_has, c_found, _ = hashset.lookup_insert(
-                cache_has, flat_ids, first)
-            n_comp = jnp.sum(first & ~c_found).astype(jnp.int32)
+    with jax.named_scope(obs.VISIT):
+        if share_cache:
+            # V_delta's domain is exactly the union of per-graph visit sets,
+            # so only membership is tracked; the values come from the batched
+            # kernel either way (lockstep hardware computes the tile
+            # regardless — DESIGN.md §3, §Perf iteration 5). #dist counters
+            # stay exact in dense mode; hash mode upper-bounds them under
+            # overflow (§9).
+            if cache_has.dtype != jnp.bool_:
+                # first-occurrence lanes only: keys distinct within each row.
+                cache_has, c_found, _ = hashset.lookup_insert(
+                    cache_has, flat_ids, first)
+                n_comp = jnp.sum(first & ~c_found).astype(jnp.int32)
+            else:
+                has = cache_has[brange[:, None], flat_ids]
+                need = flat_valid & ~has
+                scat = jnp.where(need, flat_ids, n)
+                cache_has = cache_has.at[brange[:, None], scat].set(
+                    True, mode="drop")
+                n_comp = jnp.sum(need & first).astype(jnp.int32)
         else:
-            has = cache_has[brange[:, None], flat_ids]
-            need = flat_valid & ~has
-            scat = jnp.where(need, flat_ids, n)
-            cache_has = cache_has.at[brange[:, None], scat].set(
-                True, mode="drop")
-            n_comp = jnp.sum(need & first).astype(jnp.int32)
-    else:
-        n_comp = jnp.sum(flat_valid).astype(jnp.int32)
-    n_fresh = jnp.sum(flat_valid).astype(jnp.int32)
+            n_comp = jnp.sum(flat_valid).astype(jnp.int32)
+        n_fresh = jnp.sum(flat_valid).astype(jnp.int32)
 
-    if not hash_visited:
-        scat_v = jnp.where(flat_valid, flat_ids, n).reshape(b, m, kx)
-        visited = visited.at[brange[:, None, None],
-                             mrange[None, :, None],
-                             scat_v].set(True, mode="drop")
+        if not hash_visited:
+            scat_v = jnp.where(flat_valid, flat_ids, n).reshape(b, m, kx)
+            visited = visited.at[brange[:, None, None],
+                                 mrange[None, :, None],
+                                 scat_v].set(True, mode="drop")
 
-    dists3 = dists.reshape(b, m, kx)
-    cand_ids = jnp.where(valid, nbrs, INVALID)
-    cand_dist = jnp.where(valid, dists3, jnp.inf)
-    pool_ids, pool_dist, expanded = _merge_topk(
-        pool_ids, pool_dist, expanded, cand_ids, cand_dist)
+    with jax.named_scope(obs.MERGE):
+        dists3 = dists.reshape(b, m, kx)
+        cand_ids = jnp.where(valid, nbrs, INVALID)
+        cand_dist = jnp.where(valid, dists3, jnp.inf)
+        pool_ids, pool_dist, expanded = _merge_topk(
+            pool_ids, pool_dist, expanded, cand_ids, cand_dist)
     return (pool_ids, pool_dist, expanded, visited, cache_d, cache_has,
             n_fresh, n_comp)
 
@@ -481,9 +491,15 @@ def beam_search(
         return (pool_ids, pool_dist, expanded, visited, cache_d, cache_has,
                 n_fresh + nf, n_comp + nc, hop + 1)
 
-    state = jax.lax.while_loop(cond, body, state)
-    (pool_ids, pool_dist, _, _, cache_d, cache_has,
+    # the loop's own control (the while op, its condition, the hop and
+    # counter carries) is frontier work; the body's stages name themselves
+    with jax.named_scope(obs.FRONTIER):
+        state = jax.lax.while_loop(cond, body, state)
+    (pool_ids, pool_dist, expanded, _, cache_d, cache_has,
      n_fresh, n_comp, hops) = state
+    # expanded slots still pooled: one reduction per call, not per hop (an
+    # entry pushed past ef_max by ef_max closer candidates is not counted)
+    expansions = jnp.sum(expanded & row_mask[:, None, None]).astype(jnp.int32)
     # Mask out slots beyond each graph's ef (they are not part of C(u)).
     pool_ids = jnp.where(slot_mask[None], pool_ids, INVALID)
     pool_dist = jnp.where(slot_mask[None], pool_dist, jnp.inf)
@@ -502,7 +518,7 @@ def beam_search(
         jnp.pad(pool_dist, ((0, 0), (0, 0), (0, 1)),
                 constant_values=jnp.inf), src, axis=-1)
     return SearchResult(pool_ids, pool_dist, n_fresh, n_comp, hops,
-                        cache_d, cache_has)
+                        cache_d, cache_has, expansions)
 
 
 def default_max_hops(ef_max: int, expand_width: int = 1) -> int:
@@ -592,7 +608,7 @@ def knn_search(graph_ids: jax.Array, data: jax.Array, queries: jax.Array,
         pool_i, pool_d = apply_tombstones(pool_i, pool_d, tombstone_ids)
     return SearchResult(pool_i[:, :k], pool_d[:, :k],
                         res.n_fresh, n_comp, res.hops,
-                        res.cache_d, res.cache_has)
+                        res.cache_d, res.cache_has, res.expansions)
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,7 @@ import numpy as np
 from repro.core import eval as evallib
 from repro.core import hnsw as hnswlib
 from repro.core import metric as metric_lib
+from repro.core import obs
 from repro.core.counters import BuildCounters
 from repro.core.tuner import params as pspace
 
@@ -114,7 +115,8 @@ def estimate(
     serving will actually run (and speeds the measured QPS sweeps up).
     ``build_impl="fused"`` runs each group's build with single-dispatch
     batch steps — same graphs, same counters, less dispatch overhead
-    (DESIGN.md §12).
+    (DESIGN.md §12).  Each call also appends its counters and the
+    compilations it caused to ``obs.ESTIMATES``.
     """
     ef_grid = resolve_ef_grid(k, ef_grid)
     # Prepare the data ONCE and hand the kernel form down: otherwise every
@@ -126,6 +128,7 @@ def estimate(
     queries = met.prepare(queries)
     metric = met.kernel
     ctr = BuildCounters()
+    compiles = obs.compiles()
     estimates: list[Estimate] = []
     t_build = 0.0
     t_eval = 0.0
@@ -135,25 +138,29 @@ def estimate(
     for goff in range(0, len(cfgs), group_size):
         group = cfgs[goff:goff + group_size]
         bps = [pspace.to_build_params(pg, c) for c in group]
-        t0 = time.perf_counter()
-        res = pspace.build_many(
-            pg, data, bps, seed=seed,
-            use_eso=use_eso and len(group) > 1,
-            use_epo=use_epo and len(group) > 1,
-            batch_size=build_batch_size, metric=metric,
-            visited_impl=visited_impl, expand_width=expand_width,
-            build_impl=build_impl)
-        t_build += time.perf_counter() - t0
-        ctr = ctr.add(res.counters)
-        t0 = time.perf_counter()
-        for gi, cfg in enumerate(group):
-            points = _eval_one(pg, res, gi, data, queries, gt, k, ef_grid,
-                               timing_reps, visited_impl, expand_width)
-            qps, recall = evallib.frontier_objectives(points)
-            n_dist_eval += sum(p.n_dist for p in points)
-            estimates.append(Estimate(cfg=cfg, qps=qps, recall=recall,
-                                      points=points))
-        t_eval += time.perf_counter() - t0
+        with obs.span(obs.GROUP):
+            t0 = time.perf_counter()
+            res = pspace.build_many(
+                pg, data, bps, seed=seed,
+                use_eso=use_eso and len(group) > 1,
+                use_epo=use_epo and len(group) > 1,
+                batch_size=build_batch_size, metric=metric,
+                visited_impl=visited_impl, expand_width=expand_width,
+                build_impl=build_impl)
+            t_build += time.perf_counter() - t0
+            ctr = ctr.add(res.counters)
+            t0 = time.perf_counter()
+            for gi, cfg in enumerate(group):
+                points = _eval_one(pg, res, gi, data, queries, gt, k,
+                                   ef_grid, timing_reps, visited_impl,
+                                   expand_width)
+                qps, recall = evallib.frontier_objectives(points)
+                n_dist_eval += sum(p.n_dist for p in points)
+                estimates.append(Estimate(cfg=cfg, qps=qps, recall=recall,
+                                          points=points))
+            t_eval += time.perf_counter() - t0
+    obs.ESTIMATES.append(dict(ctr.as_dict(), configs=len(cfgs),
+                              compiles=obs.compiles() - compiles))
     return EstimationRecord(estimates=estimates, counters=ctr,
                             build_seconds=t_build, eval_seconds=t_eval,
                             n_dist_eval=n_dist_eval)

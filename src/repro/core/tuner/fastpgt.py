@@ -22,6 +22,7 @@ from typing import Any
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.counters import BuildCounters
 from repro.core.tuner import baselines, estimator, pareto, vdtuner
 from repro.core.tuner import params as pspace
@@ -115,14 +116,15 @@ def tune(
     def run_estimation(xs: list[np.ndarray]):
         nonlocal t_est, ctr, n_dist_eval
         cfgs = [space.decode(x) for x in xs]
-        t0 = time.perf_counter()
-        rec = estimator.estimate(
-            pg, data, queries, gt, cfgs, k=k, ef_grid=ef_grid,
-            group_size=group_size, use_eso=eso, use_epo=epo, seed=seed,
-            build_batch_size=build_batch_size, timing_reps=timing_reps,
-            metric=metric, visited_impl=visited_impl,
-            expand_width=expand_width, build_impl=build_impl)
-        t_est += time.perf_counter() - t0
+        with obs.span(obs.ESTIMATE):
+            t0 = time.perf_counter()
+            rec = estimator.estimate(
+                pg, data, queries, gt, cfgs, k=k, ef_grid=ef_grid,
+                group_size=group_size, use_eso=eso, use_epo=epo, seed=seed,
+                build_batch_size=build_batch_size, timing_reps=timing_reps,
+                metric=metric, visited_impl=visited_impl,
+                expand_width=expand_width, build_impl=build_impl)
+            t_est += time.perf_counter() - t0
         ctr = ctr.add(rec.counters)
         n_dist_eval += rec.n_dist_eval
         for x, e in zip(xs, rec.estimates):
@@ -149,14 +151,15 @@ def tune(
         while len(cfgs_hist) < budget:
             want = min(batch if mode == "fastpgt" else 1,
                        budget - len(cfgs_hist))
-            t0 = time.perf_counter()
-            if mode == "ottertune":
-                xs = [otter.recommend(space, rng)]
-            else:
-                xs = vdtuner.recommend(
-                    mobo, space, rng, batch=want,
-                    mc_samples=mc_samples, seed=seed + 17 * it)
-            t_rec += time.perf_counter() - t0
+            with obs.span(obs.RECOMMEND):
+                t0 = time.perf_counter()
+                if mode == "ottertune":
+                    xs = [otter.recommend(space, rng)]
+                else:
+                    xs = vdtuner.recommend(
+                        mobo, space, rng, batch=want,
+                        mc_samples=mc_samples, seed=seed + 17 * it)
+                t_rec += time.perf_counter() - t0
             run_estimation(xs)
             it += 1
 

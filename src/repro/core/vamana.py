@@ -32,9 +32,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import build as build_lib
-from repro.core import commit, graph, prune, search
+from repro.core import commit, graph, obs, prune, search
 from repro.core import metric as metric_lib
-from repro.core.counters import BuildCounters, CounterTape
+from repro.core.counters import BuildCounters, CounterTape, step_row
 from repro.core.graph import INVALID, MultiGraph
 
 
@@ -94,18 +94,19 @@ def build_multi_vamana(
     tape = CounterTape()
 
     # ---- Initialization: deterministic shared random KNNG (Alg. 6 l.1-2) ---
-    init_ids = graph.random_knng_ids(seed, n, M_max)          # shared prefix
-    init_dist = graph.with_distances(data, init_ids, kform)
-    gids, gdist = [], []
-    for p in ps:
-        dm = jnp.arange(M_max)[None, :] < p.M
-        gids.append(jnp.where(dm, init_ids, INVALID))
-        gdist.append(jnp.where(dm, init_dist, jnp.inf))
-    g = MultiGraph(ids=jnp.stack(gids), dist=jnp.stack(gdist))
-    ctr.init_base += sum(n * p.M for p in ps)
-    ctr.init += n * M_max if use_eso else ctr.init_base
+    with obs.span(obs.VAMANA_INIT):
+        init_ids = graph.random_knng_ids(seed, n, M_max)      # shared prefix
+        init_dist = graph.with_distances(data, init_ids, kform)
+        gids, gdist = [], []
+        for p in ps:
+            dm = jnp.arange(M_max)[None, :] < p.M
+            gids.append(jnp.where(dm, init_ids, INVALID))
+            gdist.append(jnp.where(dm, init_dist, jnp.inf))
+        g = MultiGraph(ids=jnp.stack(gids), dist=jnp.stack(gdist))
+        ctr.init_base += sum(n * p.M for p in ps)
+        ctr.init += n * M_max if use_eso else ctr.init_base
 
-    ep = int(graph.medoid(data, kform))                       # Alg. 6 l.3
+        ep = int(graph.medoid(data, kform))                   # Alg. 6 l.3
     hops = max_hops or search.default_max_hops(L_max)
     step_kw = dict(ef_max=L_max, max_hops=hops, share_cache=use_eso,
                    use_epo=use_epo, metric=kform,
@@ -149,8 +150,8 @@ def build_multi_vamana(
                 k_in=k_in, m_max=M_max, metric=kform)
             g = MultiGraph(ids=new_ids, dist=new_dist)
             # device-side accumulation: no host round-trip per batch
-            tape.log(res.n_fresh, res.n_computed,
-                     nb + rev_checks, nc + rev_checks)
+            tape.log(step_row(res, row_mask, expand_width,
+                              nb + rev_checks, nc + rev_checks))
 
     tape.drain_into(ctr)          # the build's ONE counter host sync
     g = MultiGraph(ids=g.ids[inv_order], dist=g.dist[inv_order])

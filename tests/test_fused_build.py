@@ -48,6 +48,10 @@ def test_vamana_fused_identity(metric, visited_impl, sharing):
     a, b = _pair(vamana.build_multi_vamana, VAMANA_PS, metric=metric,
                  visited_impl=visited_impl, use_eso=sharing, use_epo=sharing)
     _assert_graphs_equal(a.g.ids, a.g.dist, b.g.ids, b.g.dist)
+    # the lockstep beam's counters are part of the compared counters
+    c = a.counters
+    assert c.batches == 3 and c.hops > 0
+    assert 0 < c.expansions <= c.lane_hops
 
 
 @pytest.mark.parametrize("metric", METRICS)
