@@ -6,7 +6,7 @@ of ``b`` queries searches ``m`` graphs simultaneously inside one
 each hop expands the ``W`` closest unexpanded pool entries per
 (query, graph) (``expand_width``, DESIGN.md §10), gathers their
 out-neighbors, computes distances through the V_delta-aware kernel and
-merges by a sorted-pool ⊕ top-k candidate merge.
+merges pool and candidates with one stable sort (``_merge_topk``).
 
 Multi-expansion (``expand_width``, DESIGN.md §10): W = 1 is the paper's
 sequential best-first schedule — builders and the estimation path pin it so
@@ -131,53 +131,39 @@ def _first_occurrence(ids: jax.Array, sentinel: int) -> jax.Array:
 
 
 def _merge_topk(pool_ids, pool_dist, expanded, cand_ids, cand_dist):
-    """Sorted-pool ⊕ top-k candidate merge (§Perf iteration 6).
+    """Sorted-pool ⊕ candidates merge, carried by one stable sort.
 
-    The pool is already sorted ascending, so only the candidates need a
-    partial sort: ``lax.top_k`` keeps the ``min(kx, ef_max)`` closest
-    (ties prefer lower index — the flat candidate order), then a rank-based
-    two-way merge places every survivor, materialized with gathers only
-    (scatters serialize on CPU and copy on accelerators).  Byte-equivalent
-    to the stable full-argsort merge over the (ef_max + kx)-wide
-    concatenation it replaces (pool entries win distance ties; verified
-    adversarially in tests/test_multi_expand.py), at O(ef·kc) compare work
-    instead of O((ef + kx)·log(ef + kx)) sort work per (query, graph).
+    One ``lax.sort`` over the concatenation [pool ‖ candidates], keyed on
+    distance and carrying ids, distances and ``expanded`` (False for the
+    candidates) as payloads, then cut to ``ef_max`` (DESIGN.md §10).  Pool
+    first plus stability is the tie rule: a pool entry outranks an
+    equal-distance candidate, and tied candidates keep their flat order.
+    Signed zeros compare equal.  No payload moves by a gather: on the TPU
+    each ``take_along_axis`` of the earlier rank merge cost ~1.3 ms a hop
+    over (256, 4, 112) pools.  The sort runs on rows flattened to 2-D: on
+    a v5e the same sort kept 3-D got other layouts and ran up to 120×
+    slower (PERF.md §5).
+
+    NaN: a NaN pool distance keys as -inf, so it keeps its slot ahead of
+    every candidate (no candidate compares below it); a NaN candidate sorts
+    after +inf and never enters.  On NaN data every search thus ends at its
+    first hop with the entry in slot 0.
 
     Args:
       pool_ids/pool_dist/expanded: (..., ef_max) sorted pools.
       cand_ids/cand_dist: (..., kx) candidates (INVALID/inf where masked).
     Returns the merged (pool_ids, pool_dist, expanded).
     """
-    ef_max = pool_ids.shape[-1]
-    kx = cand_ids.shape[-1]
-    kc = min(kx, ef_max)
-    negd, order = jax.lax.top_k(-cand_dist, kc)
-    c_dist = -negd
-    c_ids = jnp.take_along_axis(cand_ids, order, axis=-1)
-    # Each pool entry's merged rank, with the stable tie rule of the old
-    # concat-argsort (pool slots preceded candidates in the concatenation,
-    # so a pool entry outranks an equal-distance candidate).
-    cand_lt = c_dist[..., None, :] < pool_dist[..., :, None]   # (..., ef, kc)
-    rank_pool = jnp.arange(ef_max) + jnp.sum(cand_lt, axis=-1)
-    # Invert by gathering: output slot r holds pool[i] iff some pool entry
-    # has rank r (i = #pool ranks < r, strictly increasing), else candidate
-    # j = r - i — the j-th candidate is the only unplaced element left.
-    rr = jnp.arange(ef_max)
-    i_r = jnp.sum(rank_pool[..., None, :] < rr[:, None], axis=-1)
-    i_safe = jnp.minimum(i_r, ef_max - 1)
-    is_pool = jnp.take_along_axis(rank_pool, i_safe, axis=-1) == rr
-    j_safe = jnp.clip(rr - i_r, 0, kc - 1)
-    out_ids = jnp.where(is_pool,
-                        jnp.take_along_axis(pool_ids, i_safe, axis=-1),
-                        jnp.take_along_axis(c_ids, j_safe, axis=-1))
-    out_dist = jnp.where(is_pool,
-                         jnp.take_along_axis(pool_dist, i_safe, axis=-1),
-                         jnp.take_along_axis(c_dist, j_safe, axis=-1))
-    # candidates enter unexpanded
-    out_exp = jnp.where(is_pool,
-                        jnp.take_along_axis(expanded, i_safe, axis=-1),
-                        False)
-    return out_ids, out_dist, out_exp
+    lead, ef_max = pool_ids.shape[:-1], pool_ids.shape[-1]
+    key = jnp.where(jnp.isnan(pool_dist), -jnp.inf, pool_dist)
+    operands = [jnp.concatenate(pair, axis=-1) for pair in (
+        (key, cand_dist), (pool_dist, cand_dist), (pool_ids, cand_ids),
+        (expanded, jnp.zeros(cand_ids.shape, bool)))]
+    rows = [x.reshape(-1, x.shape[-1]) for x in operands]
+    _, dist, ids, exp = jax.lax.sort(rows, dimension=1, num_keys=1,
+                                     is_stable=True)
+    return tuple(x[:, :ef_max].reshape(*lead, ef_max)
+                 for x in (ids, dist, exp))
 
 
 def apply_tombstones(pool_ids, pool_dist, tomb_ids):
@@ -628,7 +614,7 @@ def _shard_search_body(graph_ids, data, global_ids, entries, shard_mask,
     corpus, which is where the recall of the merged result comes from.
     Pool ids are restored to global ids *before* any merge (a local id is
     meaningless outside its shard), then folded left-to-right in shard
-    order through the rank merge; counters psum over the mesh so every
+    order through the pool merge; counters psum over the mesh so every
     slot returns the global totals.
 
     ``*quant`` (DESIGN.md §16), when present, is this slot's
@@ -636,12 +622,12 @@ def _shard_search_body(graph_ids, data, global_ids, entries, shard_mask,
     its int8 codes and re-ranks its local ef-pool against its fp32
     ``data[s]`` *before* the global-id restore and the fold, so every
     distance that crosses a merge is an fp32 distance and the folded pool
-    stays rank-merge-sorted.  The re-rank counts add to ``n_comp``.
+    stays sorted for the merge.  The re-rank counts add to ``n_comp``.
 
     ``shard_mask`` (bool[s_loc], DESIGN.md §14) is this slot's view of the
     shard liveness mask: a dead shard searches with an all-False row mask,
     which is beam_search's zero-work state — its pool comes back all
-    INVALID/inf (rank-merging it is a no-op), its counters are 0 (so the
+    INVALID/inf (merging it is a no-op), its counters are 0 (so the
     psum'd totals count live shards only), and its hop count is 0 (so
     pmax reflects the slowest *live* shard).
     """
@@ -988,7 +974,7 @@ def sharded_knn_search(sharded_graph, queries: jax.Array, k: int, ef: int,
     subgraph with the full ``ef`` pool via the unchanged ``beam_search``
     (so ``metric`` / ``visited_impl`` / ``expand_width`` mean exactly what
     they mean unsharded); per-shard pools come back in shard-local ids,
-    are restored to global ids, and merge through the same rank merge the
+    are restored to global ids, and merge through the same pool merge the
     in-loop pool update uses (``_merge_topk`` — earlier shards win
     distance ties, matching a serial fold).  Counter semantics: ``n_fresh``
     / ``n_computed`` are psum-reduced totals over all shards (the cost of
@@ -1021,7 +1007,7 @@ def sharded_knn_search(sharded_graph, queries: jax.Array, k: int, ef: int,
     degraded-mode serving: dead shards are excluded from BOTH routing
     (their centroid scores mask to +inf so ``route_topk`` never picks
     them) and the merge (their scatter-gather pools search under an
-    all-False row mask, returning INVALID/inf that rank-merge as no-ops),
+    all-False row mask, returning INVALID/inf that merge as no-ops),
     and the psum'd counters count live-shard work only.  An all-False
     mask raises (no live shard can answer); ``routed_shards`` above the
     live count clamps down with a warning.  ``shard_mask=None`` (and any

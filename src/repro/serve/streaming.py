@@ -14,7 +14,7 @@ corpus needs:
      incrementally rebuilt Vamana over the delta prefix (rebuilt on
      occupancy doublings — amortized O(log C) rebuilds per fill).  Delta
      candidates fold into the main-graph ef-pool through the same
-     ``search._merge_topk`` rank merge the in-loop pool update uses, under
+     ``search._merge_topk`` pool merge the in-loop pool update uses, under
      the existing bit-pinned tie rule (main-pool entries win distance
      ties).
 

@@ -1,7 +1,8 @@
-"""Width-W multi-expansion search (DESIGN.md §10): top-k pool merge
-byte-equivalence vs the pre-refactor argsort merge (adversarial ties /
-INVALID padding), W=1 dense bit-identity end to end, and W>1 recall parity
-at 10k across metrics and visited impls."""
+"""Width-W multi-expansion search (DESIGN.md §10): sort-carried pool merge
+byte-equivalence vs the stable argsort merge (adversarial ties / INVALID
+padding), no gather in the merge, its NaN rule, W=1 dense bit-identity end
+to end, and W>1 recall parity at 10k across metrics and visited impls."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -49,7 +50,11 @@ def _random_cands(r, b, m, kx, p_invalid, quant):
 
 
 @pytest.mark.parametrize("ef,kx", [(8, 8), (16, 4), (16, 64), (32, 128),
-                                   (1, 16)])
+                                   (1, 16),
+                                   # the estimate's build (L_max 112 / 96)
+                                   # and eval sweep (ef 80) at M_max 16,
+                                   # and serving (ef 64, W=4 x M=32)
+                                   (112, 16), (96, 16), (80, 16), (64, 128)])
 @pytest.mark.parametrize("quant,p_invalid,n_valid_frac", [
     (4, 0.3, 1.0),      # massive ties, some INVALID candidates
     (1, 0.0, 1.0),      # EVERY distance identical: pure tie-order test
@@ -82,6 +87,58 @@ def test_topk_merge_tie_priority_pool_wins():
     np.testing.assert_array_equal(np.asarray(ids), [[[1, 2, 7]]])
     np.testing.assert_array_equal(np.asarray(exp), [[[True, False, False]]])
     np.testing.assert_array_equal(np.asarray(dist), [[[0.5, 0.5, 0.5]]])
+
+
+def test_merge_moves_no_payload_by_gather():
+    """The merge carries ids, distances and flags through one sort: its
+    jaxpr holds no gather (each cost ~1.3 ms a hop on the TPU)."""
+    pool = (jnp.zeros((2, 4, 112), jnp.int32),
+            jnp.zeros((2, 4, 112), jnp.float32),
+            jnp.zeros((2, 4, 112), bool))
+    cands = (jnp.zeros((2, 4, 16), jnp.int32),
+             jnp.zeros((2, 4, 16), jnp.float32))
+    jaxpr = jax.make_jaxpr(search._merge_topk)(*pool, *cands)
+    prims = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+    assert "sort" in prims
+    assert not {p for p in prims if "gather" in p}, prims
+
+
+def test_merge_nan_rule():
+    """A NaN pool distance keeps its slot ahead of every candidate; a NaN
+    candidate, of either sign, never enters; finite candidates still do."""
+    pi = jnp.asarray([[[5, INVALID, INVALID, INVALID]]], jnp.int32)
+    pd = jnp.asarray([[[jnp.nan, jnp.inf, jnp.inf, jnp.inf]]], jnp.float32)
+    pe = jnp.asarray([[[True, False, False, False]]])
+    ci = jnp.asarray([[[7, 8, 9]]], jnp.int32)
+    cd = jnp.asarray([[[jnp.nan, 0.5, -jnp.nan]]], jnp.float32)
+    ids, dist, exp = search._merge_topk(pi, pd, pe, ci, cd)
+    np.testing.assert_array_equal(np.asarray(ids),
+                                  [[[5, 8, INVALID, INVALID]]])
+    np.testing.assert_array_equal(np.asarray(exp),
+                                  [[[True, False, False, False]]])
+    np.testing.assert_array_equal(np.asarray(dist),
+                                  [[[np.nan, 0.5, np.inf, np.inf]]])
+
+
+@pytest.mark.parametrize("m", [1, 2])
+def test_nan_search_ends_at_first_hop_keeping_entry(m):
+    """On NaN data and queries no distance enters a pool: every search ends
+    at its first hop with the entry alone in slot 0 (a warm-up on NaN data
+    compiles every program and does no more work than that)."""
+    n, b, mx = 200, 8, 8
+    data = jnp.full((n, 16), jnp.nan, jnp.float32)
+    queries = jnp.full((b, 16), jnp.nan, jnp.float32)
+    g = jnp.stack([random_knng_ids(i, n, mx) for i in range(m)])
+    entry = jnp.tile(jnp.arange(3, 3 + m, dtype=jnp.int32), (b, 1))
+    res = search.beam_search(
+        g, data, queries, jnp.full((b,), INVALID, jnp.int32),
+        jnp.ones((b,), bool), jnp.full((m,), 16, jnp.int32), entry,
+        ef_max=16, max_hops=40, share_cache=m > 1)
+    assert int(res.hops) == 1
+    ids = np.asarray(res.pool_ids)
+    np.testing.assert_array_equal(ids[:, :, 0], np.asarray(entry))
+    assert np.all(ids[:, :, 1:] == INVALID)
+    assert np.all(np.isnan(np.asarray(res.pool_dist)[:, :, 0]))
 
 
 def test_w1_dense_search_bit_identical_to_argsort_reference(small_dataset):

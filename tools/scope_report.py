@@ -71,7 +71,8 @@ def load(path: str, device_plane=DEVICE_PLANE, device_line=DEVICE_LINE,
     ``device_line`` is a prefix (or tuple of prefixes) of the op lines'
     names.  With ``module_line`` None each op's module comes from its own
     ``hlo_module``/``program_id`` stats (the CPU has no module line), a
-    lookup per event that only a small trace affords."""
+    lookup per event that only a small trace affords; events without an
+    ``hlo_op`` stat are the CPU runtime's, not ops, and are left out."""
     from jax.profiler import ProfileData
     ops, modules, host = {}, {}, []
     for plane in ProfileData.from_file(path).planes:
@@ -83,16 +84,19 @@ def load(path: str, device_plane=DEVICE_PLANE, device_line=DEVICE_LINE,
                     # each client thread's line apart, as its ops nest
                     evs = ops.setdefault(f"{plane.name}|{line.name}", [])
                     for e in line.events:
-                        m = OP_NAME.match(e.name)
                         end = e.start_ns + e.duration_ns
-                        evs.append((m.group(1) if m else e.name, e.start_ns,
-                                    end))
                         if module_line is None:
                             st = dict(e.stats)
-                            if "hlo_module" in st:
-                                mods.append((f"{st['hlo_module']}"
-                                             f"({st['program_id']})",
-                                             e.start_ns, end))
+                            if "hlo_op" not in st:
+                                # the CPU client's own events around the
+                                # ops (ThunkExecutor::Execute, "end: <op>")
+                                continue
+                            mods.append((f"{st['hlo_module']}"
+                                         f"({st['program_id']})",
+                                         e.start_ns, end))
+                        m = OP_NAME.match(e.name)
+                        evs.append((m.group(1) if m else e.name, e.start_ns,
+                                    end))
                 elif line.name == module_line:
                     mods.extend((e.name, e.start_ns,
                                  e.start_ns + e.duration_ns)
