@@ -239,6 +239,43 @@ def rerank_pool(queries, data, pool_ids, *, metric):
             jnp.sum(valid).astype(jnp.int32))
 
 
+def _frontier_pick(pool_ids, expanded, slot_mask, row_mask, width):
+    """The ``width`` closest unexpanded slots of each pool, marked expanded.
+
+    The pool is sorted ascending, so the closest unexpanded slot is the
+    first one: ``min(where(unexp, slot, ef_max))`` over the slot axis, and
+    each further one is the same minimum with the slots already taken
+    cleared (a static loop over ``width``).  A pool with fewer than W
+    unexpanded slots gets the sentinel ``ef_max``, which expands nothing.
+    The one-hot slot mask of the picks then reads their ids and marks them
+    expanded by reductions, so the pick holds no sort, gather or scatter: on
+    a v5e the ``lax.top_k`` it replaces cost ~354 µs a hop over
+    (256, 4, 112) pools (DESIGN.md §10).
+
+    Args:
+      pool_ids/expanded: (b, m, ef_max) sorted pools and their flags.
+      slot_mask: bool[m, ef_max], the slots inside each graph's ``ef``.
+      row_mask: bool[b], False for padding rows (which expand nothing).
+      width: W, static, at most ``ef_max``.
+    Returns (act, u, expanded): bool[b, m, W] the lanes that expand,
+    int32[b, m, W] their node ids (0 where not ``act``), and ``expanded``
+    with the picked slots set.
+    """
+    ef_max = pool_ids.shape[-1]
+    slot = jnp.arange(ef_max)
+    unexp = (pool_ids != INVALID) & ~expanded & slot_mask[None]
+    picks = []
+    for _ in range(width):
+        first = jnp.min(jnp.where(unexp, slot, ef_max), axis=-1)   # (b, m)
+        picks.append(first)
+        unexp = unexp & (slot != first[..., None])
+    sel = jnp.stack(picks, axis=-1)                                # (b, m, W)
+    act = (sel < ef_max) & row_mask[:, None, None]
+    hit = (slot == sel[..., None]) & act[..., None]          # (b, m, W, ef)
+    u = jnp.max(jnp.where(hit, pool_ids[..., None, :], 0), axis=-1)
+    return act, u, expanded | jnp.any(hit, axis=-2)
+
+
 def _expand_all_graphs(graph_ids, data, queries, query_ids, row_mask,
                        slot_mask, pool_ids, pool_dist, expanded,
                        visited, cache_d, cache_has, share_cache, metric,
@@ -256,7 +293,7 @@ def _expand_all_graphs(graph_ids, data, queries, query_ids, row_mask,
     [b, m, S] hash-key table (dispatch on dtype; DESIGN.md §9), and
     ``cache_has`` likewise bool[b, n] or int32[b, S'].
     """
-    b, m, ef_max = pool_ids.shape
+    b, m = pool_ids.shape[:2]
     n = _corpus_len(data)
     mx = graph_ids.shape[2]
     kx = width * mx
@@ -265,22 +302,9 @@ def _expand_all_graphs(graph_ids, data, queries, query_ids, row_mask,
     hash_visited = visited.dtype != jnp.bool_
 
     with jax.named_scope(obs.FRONTIER):
-        unexp = (pool_ids != INVALID) & (~expanded) & slot_mask[None]
-        # W closest unexpanded slots: the pool is sorted ascending, so these
-        # are the first W unexpanded slot positions (top_k of the negated
-        # position; position ef_max = "no slot" sentinel).
-        slot_pos = jnp.where(unexp, jnp.arange(ef_max), ef_max)
-        neg_sel, _ = jax.lax.top_k(-slot_pos, width)
-        sel = -neg_sel                                           # (b, m, W)
-        act = (sel < ef_max) & row_mask[:, None, None]           # (b, m, W)
-        sel_safe = jnp.minimum(sel, ef_max - 1)
-        u = jnp.take_along_axis(pool_ids, sel_safe, axis=-1)     # (b, m, W)
-        u_safe = jnp.where(act, jnp.maximum(u, 0), 0)
-        expanded = expanded.at[brange[:, None, None], mrange[None, :, None],
-                               jnp.where(act, sel_safe, ef_max)].set(
-            True, mode="drop")
-
-        nbrs = graph_ids[mrange[None, :, None], u_safe]      # (b, m, W, Mx)
+        act, u, expanded = _frontier_pick(pool_ids, expanded, slot_mask,
+                                          row_mask, width)
+        nbrs = graph_ids[mrange[None, :, None], u]           # (b, m, W, Mx)
         nbrs = nbrs.reshape(b, m, kx)
         nbrs_safe = jnp.maximum(nbrs, 0)
         # same-id duplicates within one (query, graph) hop count/insert once

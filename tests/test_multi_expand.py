@@ -1,7 +1,9 @@
 """Width-W multi-expansion search (DESIGN.md §10): sort-carried pool merge
 byte-equivalence vs the stable argsort merge (adversarial ties / INVALID
-padding), no gather in the merge, its NaN rule, W=1 dense bit-identity end
-to end, and W>1 recall parity at 10k across metrics and visited impls."""
+padding), no gather in the merge, its NaN rule, the sort-free frontier pick
+byte-equal to the top_k pick it replaced (alone and end to end), W=1 dense
+bit-identity end to end, and W>1 recall parity at 10k across metrics and
+visited impls."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,6 +29,37 @@ def argsort_merge(pool_ids, pool_dist, expanded, cand_ids, cand_dist):
     return (jnp.take_along_axis(all_ids, order, axis=-1),
             jnp.take_along_axis(all_dist, order, axis=-1),
             jnp.take_along_axis(all_exp, order, axis=-1))
+
+
+def topk_pick(pool_ids, expanded, slot_mask, row_mask, width):
+    """The earlier frontier pick: ``lax.top_k`` of the negated unexpanded
+    slot position, a ``take_along_axis`` read and a scatter — the byte-level
+    oracle the sort-free pick must reproduce."""
+    b, m, ef_max = pool_ids.shape
+    unexp = (pool_ids != INVALID) & (~expanded) & slot_mask[None]
+    slot_pos = jnp.where(unexp, jnp.arange(ef_max), ef_max)
+    sel = -jax.lax.top_k(-slot_pos, width)[0]
+    act = (sel < ef_max) & row_mask[:, None, None]
+    sel_safe = jnp.minimum(sel, ef_max - 1)
+    u = jnp.take_along_axis(pool_ids, sel_safe, axis=-1)
+    u_safe = jnp.where(act, jnp.maximum(u, 0), 0)
+    expanded = expanded.at[jnp.arange(b)[:, None, None],
+                           jnp.arange(m)[None, :, None],
+                           jnp.where(act, sel_safe, ef_max)].set(
+        True, mode="drop")
+    return act, u_safe, expanded
+
+
+def _primitives(jaxpr):
+    """Every primitive name in a jaxpr, nested sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+                elif hasattr(sub, "jaxpr"):
+                    yield from _primitives(sub.jaxpr)
 
 
 def _random_pool(r, b, m, ef, n_valid, quant):
@@ -118,6 +151,100 @@ def test_merge_nan_rule():
                                   [[[True, False, False, False]]])
     np.testing.assert_array_equal(np.asarray(dist),
                                   [[[np.nan, 0.5, np.inf, np.inf]]])
+
+
+@pytest.mark.parametrize("share", [0.0, 0.05, 0.5, 1.0])
+@pytest.mark.parametrize("width", [1, 2, 4, 7])
+def test_frontier_pick_byte_equals_topk_pick(width, share):
+    """The min-reduction pick returns the top_k pick's lanes, ids and flags
+    at the build's pool shape (64, 4, 112) for ``share`` of the live slots
+    unexpanded, with fully expanded rows, INVALID tails, per-graph
+    ``slot_mask``s shorter than ef_max and ``row_mask`` padding."""
+    b, m, ef_max = 64, 4, 112
+    r = np.random.default_rng(width * 100 + int(share * 100))
+    slot = np.arange(ef_max)
+    n_valid = r.integers(0, ef_max + 1, size=(b, m, 1))
+    n_valid[:4] = ef_max                               # full pools
+    ids = np.argsort(r.random((b, m, ef_max)), axis=-1).astype(np.int32)
+    ids = np.where(slot < n_valid, ids, INVALID).astype(np.int32)
+    exp = (r.random((b, m, ef_max)) >= share) & (slot < n_valid)
+    exp[4:12] = slot < n_valid[4:12]                   # nothing unexpanded
+    slot_mask = slot[None, :] < np.array([ef_max, 80, 33, 7])[:, None]
+    row_mask = r.random(b) > 0.2
+    row_mask[:2] = [True, False]
+    args = (jnp.asarray(ids), jnp.asarray(exp), jnp.asarray(slot_mask),
+            jnp.asarray(row_mask))
+    got = search._frontier_pick(*args, width)
+    ref = topk_pick(*args, width)
+    for g, e, name in zip(got, ref, ("act", "u", "expanded")):
+        assert g.dtype == e.dtype and g.shape == e.shape, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e),
+                                      err_msg=f"pick {name} diverged")
+    if share == 1.0:
+        assert np.asarray(got[0]).any()
+
+
+def test_frontier_pick_holds_no_sort_gather_or_scatter():
+    """The frontier pick is reductions only: its jaxpr holds no sort or
+    top_k (the 3-D top_k cost ~354 µs a hop on the TPU), and no gather or
+    scatter for the id read and the ``expanded`` update."""
+    pool_ids = jnp.zeros((2, 4, 112), jnp.int32)
+    expanded = jnp.zeros((2, 4, 112), bool)
+    slot_mask = jnp.ones((4, 112), bool)
+    row_mask = jnp.ones((2,), bool)
+    for width in (1, 4):
+        jaxpr = jax.make_jaxpr(search._frontier_pick, static_argnums=4)(
+            pool_ids, expanded, slot_mask, row_mask, width)
+        prims = set(_primitives(jaxpr.jaxpr))
+        assert "reduce_min" in prims, prims
+        banned = {p for p in prims
+                  if any(w in p for w in ("sort", "top_k", "gather",
+                                          "scatter"))}
+        assert not banned, (width, banned)
+
+
+@pytest.mark.parametrize("schedule", ["w1_eso_multigraph", "w4_hash"])
+def test_search_bit_identical_to_topk_pick_reference(small_dataset,
+                                                     schedule):
+    """End to end, the sort-free pick returns the top_k pick's pools,
+    distances, counters and hops: the build's W=1 multi-graph ESO search
+    and serving's W=4 hash-mode search with padding rows."""
+    data, queries = small_dataset
+    adj, _ = knng.build_knng(data, 10)
+    if schedule == "w1_eso_multigraph":
+        pad = jnp.full((adj.shape[0], 4), INVALID, jnp.int32)
+        g2 = jnp.stack([adj, jnp.concatenate([adj[:, :6], pad], axis=1)])
+        b = 8
+        args = (g2, data, queries[:b], jnp.full((b,), INVALID, jnp.int32),
+                jnp.ones((b,), bool), jnp.array([15, 10], jnp.int32),
+                jnp.zeros((b, 2), jnp.int32))
+
+        def run():
+            return search.beam_search(*args, ef_max=15, max_hops=60,
+                                      share_cache=True)
+    else:
+        row_mask = jnp.arange(queries.shape[0]) < queries.shape[0] - 5
+
+        def run():
+            return search.knn_search(adj, data, queries, 10, 32, 0,
+                                     visited_impl="hash", expand_width=4,
+                                     row_mask=row_mask)
+    new = run()
+    orig = search._frontier_pick
+    search._frontier_pick = topk_pick
+    search.beam_search.clear_cache()
+    try:
+        ref = run()
+    finally:
+        search._frontier_pick = orig
+        search.beam_search.clear_cache()
+    np.testing.assert_array_equal(np.asarray(new.pool_ids),
+                                  np.asarray(ref.pool_ids))
+    np.testing.assert_array_equal(np.asarray(new.pool_dist),
+                                  np.asarray(ref.pool_dist))
+    for name in ("n_fresh", "n_computed", "hops"):
+        assert int(getattr(new, name)) == int(getattr(ref, name)), name
+    assert int(new.hops) > 1
 
 
 @pytest.mark.parametrize("m", [1, 2])
