@@ -20,7 +20,6 @@ import traceback
 
 BENCHES = [
     ("kernel", "benchmarks.kernel_microbench", {}),
-    ("build", "benchmarks.build_bench", {}),
     ("fig4", "benchmarks.fig4_build_breakdown", {}),
     ("fig5", "benchmarks.fig5_nlo_overlap", {}),
     ("table2", "benchmarks.table2_repeated_dist", {}),
@@ -31,7 +30,7 @@ BENCHES = [
     ("fig7_9", "benchmarks.fig7_9_tuning_quality", {}),
 ]
 
-QUICK = {"kernel", "build", "fig4", "fig5", "table2"}
+QUICK = {"kernel", "fig4", "fig5", "table2"}
 
 
 def main() -> None:
@@ -50,11 +49,11 @@ def main() -> None:
             continue
         try:
             mod = importlib.import_module(module)
-            if name in ("kernel", "build") and args.quick:
-                # quick mode must reach these benches: it selects the
-                # small sweep AND routes their JSON to the gitignored
-                # quick files instead of clobbering the committed
-                # trajectories (BENCH_search.json / BENCH_build.json)
+            if name == "kernel" and args.quick:
+                # quick mode must reach this bench: it selects the small
+                # sweep AND routes its JSON to the gitignored quick file
+                # instead of clobbering the committed trajectory
+                # (BENCH_search.json)
                 kw = {**kw, "quick": True}
             mod.run(**kw)
         except Exception:

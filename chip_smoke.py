@@ -24,15 +24,14 @@ Phases (one chip):
            at d=128 and d=960 against NumPy float64, the prune stage's
            candidate distances likewise, and the jitted search program's
            HLO checked for ``tpu_custom_call`` (Pallas, not ``ref.py``).
-  tune     ``fastpgt.tune("vamana", mode="fastpgt", budget=8, batch=4,
-           build_impl="fused")`` at n=TUNE_N; the configuration with the
-           best recall is rebuilt and must reach recall@10 >= 0.9 on the
-           host's truth.
+  tune     ``fastpgt.tune("vamana", mode="fastpgt", budget=8, batch=4)``
+           at n=TUNE_N; the configuration with the best recall is rebuilt
+           and must reach recall@10 >= 0.9 on the host's truth.
   serve    that configuration built at n=SERVE_N with
-           ``build_index(metric="l2", build_impl="fused", quantize="sq8")``
-           and searched with the serving defaults (hash visited set, W=4)
-           on 1,000 queries, fp32 and sq8, at the smallest ef in SERVE_EFS
-           where fp32 recall@10 >= 0.9; sq8 must be within 0.02 of it.
+           ``build_index(metric="l2", quantize="sq8")`` and searched with
+           the serving defaults (hash visited set, W=4) on 1,000 queries,
+           fp32 and sq8, at the smallest ef in SERVE_EFS where fp32
+           recall@10 >= 0.9; sq8 must be within 0.02 of it.
 
 Phase ``sharded`` (``--chips 4``, and nothing else): the same corpus built
 with ``num_shards=4, assign="kmeans"`` on a 4-device ``search_mesh``,
@@ -278,15 +277,14 @@ def phase_tune(seed: int):
     data, queries = sift_shaped(TUNE_N, seed)
     truth = host_knn(data, queries)
     res = fastpgt.tune("vamana", data, queries, mode="fastpgt", budget=8,
-                       batch=4, build_impl="fused")
+                       batch=4)
     log(f"tune summary {json.dumps(res.summary())}")
     best = max(range(len(res.cfgs)),
                key=lambda i: (res.objectives[i][1], res.objectives[i][0]))
     params = pspace.to_build_params("vamana", res.cfgs[best])
     check(isinstance(params, vamana.VamanaParams),
           f"tune returned a non-Vamana configuration {params!r}")
-    idx = retrieval.build_index(data, data, params, metric="l2",
-                                build_impl="fused")
+    idx = retrieval.build_index(data, data, params, metric="l2")
     _, sr = retrieval.retrieval_attention_batched(idx, queries, top_k=K,
                                                   ef=SERVE_EF)
     rec = recall(sr.pool_ids, truth)
@@ -307,7 +305,7 @@ def phase_serve(seed: int, params) -> None:
     data, queries = sift_shaped(SERVE_N, seed)
     truth = host_knn(data, queries)
     idx = retrieval.build_index(data, data, params, metric="l2",
-                                build_impl="fused", quantize="sq8")
+                                quantize="sq8")
     t_build = time.perf_counter() - t0
     log(f"serve: built n={SERVE_N} {params} in {t_build:.1f} s")
     by_ef = {}
@@ -348,8 +346,7 @@ def phase_sharded(seed: int, devices) -> None:
     truth = host_knn(data, queries)
     params = vamana.VamanaParams(**SHARDED_PARAMS)
     idx = retrieval.build_index(data, data, params, metric="l2",
-                                num_shards=SHARDS, assign="kmeans",
-                                build_impl="fused")
+                                num_shards=SHARDS, assign="kmeans")
     log(f"sharded: built n={SERVE_N} in {time.perf_counter() - t0:.1f} s")
     sg = idx.shards
     placed = sg.ids.sharding.device_set

@@ -1,16 +1,14 @@
-"""Fused multi-graph build step — one compiled dispatch per insertion batch.
+"""Multi-graph build step — one compiled dispatch per insertion batch.
 
 The paper's headline speedup comes from constructing many PGs
-simultaneously so repeated computation is paid once (PAPER.md); the
-per-batch host loop the builders originally ran paid that saving back in
-dispatch overhead: every batch issued one ``beam_search`` dispatch, m
-``rng_prune`` dispatches, m ``add_reverse_edges`` dispatches, and dozens of
-eager ops (transposes, candidate merges, scatter commits) — roughly
-``1 + 3m`` compiled dispatches plus eager-op traffic per batch, with
+simultaneously so repeated computation is paid once (PAPER.md).  Driving
+each batch's stages from the host would pay that saving back in dispatch
+overhead: one ``beam_search`` dispatch, m ``rng_prune`` and m
+``add_reverse_edges`` dispatches and dozens of eager ops per batch, with
 ``int(...)`` counter casts blocking the host after each one.
 
-This module fuses the whole search → mPrune → commit batch step into ONE
-jitted function (DESIGN.md §12):
+This module is the builders' one driver: the whole search → mPrune → commit
+batch step is ONE jitted function (DESIGN.md §12):
 
   ``insert_batch``      the Vamana/HNSW-shaped step (search the evolving
                         graph, prune, commit) — a single dispatch per batch,
@@ -24,23 +22,17 @@ jitted function (DESIGN.md §12):
                         the host dispatches once per build pass and never
                         blocks mid-build.
 
-The fused step *traces the same functions the per_batch loop dispatches*
-(``search.beam_search``, ``prune.multi_prune``, ``commit.commit_group``)
-— there is no separate fused algorithm to drift.  Graphs and counters are
-bit-identical to per_batch at test scale (pinned by
-tests/test_fused_build.py), and ``fused_vamana_pass`` is exactly
-bit-identical to dispatching ``insert_batch`` per batch at every scale
-measured.  Versus the *legacy staged* path there is one documented FP
-deviation (DESIGN.md §12): per_batch runs the prune stage's
-candidate-distance reduction eagerly, the fused step compiles it, and the
-differing accumulation orders flip ppm-level near-ties in the dominance
-checks (≤4e-5 of prune counters at n=20k; bounded per cell by
-benchmarks/build_bench.py, exact deltas recorded in BENCH_build.json).
+The step traces the stage functions (``search.beam_search``,
+``prune.multi_prune``, ``commit.commit_group``) in order — there is no
+separate algorithm to drift.  Two facts are pinned by
+tests/test_fused_build.py: (1) ``fused_vamana_pass`` is bit-identical to
+dispatching ``insert_batch`` once per batch (the ``fori_loop`` adds
+nothing); (2) one jitted step is bit-identical to calling the stage
+functions in order on the same state.
 
-Shapes are static and bucketed exactly as in the per_batch path
-(``graph.bucket`` on L_max/M_max, fixed ``batch_size``), so one XLA compile
-of the fused step is reused across every batch of every tuning iteration
-that shares the bucketed shape.
+Shapes are static and bucketed (``graph.bucket`` on L_max/M_max, fixed
+``batch_size``), so one XLA compile of the step is reused across every
+batch of every tuning iteration that shares the bucketed shape.
 """
 from __future__ import annotations
 
@@ -52,13 +44,16 @@ import jax.numpy as jnp
 from repro.core import commit, counters as counters_lib, obs, prune, search
 from repro.core.graph import INVALID
 
-BUILD_IMPLS = ("per_batch", "fused")
+BUILD_IMPLS = ("fused",)
 
 
 def resolve_build_impl(build_impl: str) -> str:
+    """Check the ``build_impl`` keyword the entry points still accept: the
+    build has one driver, the compiled step of this module."""
     if build_impl not in BUILD_IMPLS:
         raise ValueError(
-            f"build_impl {build_impl!r} not in {BUILD_IMPLS}")
+            f"build_impl {build_impl!r} not in {BUILD_IMPLS}: the build has "
+            f"one driver, the compiled step (DESIGN.md §12)")
     return build_impl
 
 
@@ -68,13 +63,11 @@ def _insert_step(graph_ids, graph_dist, data, u, row_mask, queries, L, M,
                  k_in, m_max):
     """Traced body of one insertion batch: search → mPrune → commit.
 
-    Literally the statements the per_batch builder loop runs, gathered into
-    one traceable function — counters come back as device scalars instead
-    of Python-int mutations, everything else is unchanged (bit-identity by
-    construction).  Returns ``(new_ids, new_dist, ctr_row, top_ids,
-    cache_d, cache_has)`` where ``ctr_row`` is the int32[7] CounterTape row
-    and ``top_ids`` is each (query, graph)'s closest pool entry (HNSW's
-    next-layer entry points; Vamana ignores it).
+    The stage functions called in order in one traceable function, with
+    counters returned as device scalars.  Returns ``(new_ids, new_dist,
+    ctr_row, top_ids, cache_d, cache_has)`` where ``ctr_row`` is the
+    int32[7] CounterTape row and ``top_ids`` is each (query, graph)'s
+    closest pool entry (HNSW's next-layer entry points; Vamana ignores it).
     """
     qids = jnp.where(row_mask, u, INVALID)
     res = search.beam_search(
@@ -120,9 +113,11 @@ def _nsg_step(search_graph_ids, graph_ids, graph_dist, knn_ids, knn_dist,
     """Traced body of one NSG insertion batch (search on the static KNNG,
     candidates = search pool ∪ the node's own KNNG row, prune, commit).
 
-    Same statement-for-statement transplant from ``nsg.build_multi_nsg``'s
-    per_batch loop as ``_insert_step`` is from Vamana's — the KNNG-row
-    merge/dedup included — so graphs and counters stay bit-identical."""
+    NSG's prune candidates are the nodes *visited* during search; the pool
+    alone loses u's local KNNG structure, so each node's own KNNG row (exact
+    distances already known — no extra #dist) is merged with the search
+    pool, deduplicated (first occurrence after a sort by distance) and
+    sorted ascending, as prune requires."""
     n = data.shape[0]
     qids = jnp.where(row_mask, u, INVALID)
     res = search.beam_search(
@@ -185,11 +180,11 @@ def fused_vamana_pass(graph_ids, graph_dist, data, L, M, alpha, ep, *,
     """Device-resident Vamana main pass: every insertion batch inside ONE
     compiled dispatch (``lax.fori_loop`` over batches).
 
-    The loop body reproduces the per_batch host loop's batch construction
-    exactly — ``u`` padded with ``n`` past the corpus, padding rows masked,
-    queries gathered at ``min(u, n-1)`` — then runs ``_insert_step``, so
-    the final graphs and the per-batch counter rows are bit-identical to
-    ``build_impl="per_batch"``.  Counter rows land in a preallocated
+    The loop body builds each batch — ``u`` padded with ``n`` past the
+    corpus, padding rows masked, queries gathered at ``min(u, n-1)`` — then
+    runs ``_insert_step``, so the final graphs and the per-batch counter
+    rows are bit-identical to dispatching ``insert_batch`` once per batch.
+    Counter rows land in a preallocated
     int32[n_batches, 7] log (no int64 carry needed: the host sums the
     fetched log in int64), returned alongside the graphs for one
     ``CounterTape.log_many`` + end-of-build sync.

@@ -77,10 +77,10 @@ def step_row(res, row_mask, expand_width, n_prune_base=0,
 class CounterTape:
     """Device-side accumulation of per-step counter rows.
 
-    Build loops used to close every batch with ``int(res.n_fresh)`` casts —
-    four host round-trips per dispatched batch, each blocking the host until
-    the device drained its queue, which serializes dispatch even on the
-    per_batch path.  The tape instead *logs* each step's int32[7] counter
+    Closing every batch with ``int(res.n_fresh)`` casts would cost host
+    round-trips per dispatched batch, each blocking the host until the
+    device drained its queue, which serializes dispatch.  The tape instead
+    *logs* each step's int32[7] counter
     row as a device array (async, never blocks) and syncs exactly once:
     ``drain_into`` stacks the rows, fetches them in one transfer, and sums
     on the host in int64 (no int32 overflow however long the build ran).

@@ -10,11 +10,11 @@ inserted node is shared across *all* m graphs and *all* layers (Alg. 5 l.7).
 Storage: ids int32[n_layers, m, n, M_max] — dense per layer (laptop-scale
 simplicity; upper layers hold ~n/M rows).  alpha = 1 everywhere (HNSW).
 
-``build_impl`` (DESIGN.md §12): "fused" replaces each layer's
-search + mPrune + commit triple with one ``core/build.insert_batch``
-dispatch (the greedy ef=1 descent between layers stays a plain search
-dispatch); "per_batch" keeps the host-driven stages.  Both accumulate
-counters on device (CounterTape) and sync once at the end of the build.
+The schedule of bootstrap batches and layers depends on the data, so it
+stays a host loop (DESIGN.md §12): each layer's search + mPrune + commit is
+one ``core/build.insert_batch`` dispatch, and the greedy ef=1 descent between
+layers is a plain search dispatch.  Counters accumulate on device
+(CounterTape) and sync once at the end of the build.
 """
 from __future__ import annotations
 
@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import build as build_lib
-from repro.core import commit, graph, hashset, prune, search
+from repro.core import graph, hashset, search
 from repro.core import metric as metric_lib
 from repro.core.counters import BuildCounters, CounterTape, step_row
 from repro.core.graph import INVALID
@@ -75,9 +75,7 @@ def build_multi_hnsw(
     metric: str = "l2",
     visited_impl: str = "dense",
     expand_width: int = 1,
-    build_impl: str = "per_batch",
 ) -> HNSWBuildResult:
-    build_impl = build_lib.resolve_build_impl(build_impl)
     met = metric_lib.resolve(metric)
     data = met.prepare(data)      # normalize ONCE for cosine (no-op otherwise)
     kform = met.kernel
@@ -155,38 +153,12 @@ def build_multi_hnsw(
                     desc_mask[:, None] & (got != INVALID), got, next_entry)
             if ins_np.any():    # search + mPrune + commit, Alg. 5 l.13-19
                 ins_mask = jnp.array(ins_np)
-                if build_impl == "fused":
-                    # ONE dispatch for search + mPrune + commit of this
-                    # layer's inserts (DESIGN.md §12).
-                    nl, nd, row, got, cache_d, cache_has = (
-                        build_lib.insert_batch(
-                            lids[layer], ldist[layer], data, u, ins_mask,
-                            queries, efc, M, alpha1, entry,
-                            cache_d, cache_has, **step_kw))
-                    tape.log(row)
-                else:
-                    res = search.beam_search(
-                        lids[layer], data, queries, qids, ins_mask,
-                        efc, entry, cache_d, cache_has,
-                        ef_max=efc_max, max_hops=hops, share_cache=use_eso,
-                        metric=kform, visited_impl=visited_impl,
-                        expand_width=expand_width)
-                    cache_d, cache_has = res.cache_d, res.cache_has
-                    got = res.pool_ids[:, :, 0]
-
-                    cand_ids = jnp.transpose(res.pool_ids, (1, 0, 2))
-                    cand_dist = jnp.transpose(res.pool_dist, (1, 0, 2))
-                    valid = cand_ids != INVALID
-                    pruned, nb, nc = prune.multi_prune(
-                        data, cand_ids, cand_dist, valid, M, alpha1,
-                        m_max=M_max, use_epo=use_epo, metric=kform)
-                    nl, nd, rev_checks = commit.commit_group(
-                        data, lids[layer], ldist[layer], u, pruned,
-                        ins_mask, M, alpha1, k_in=k_in, m_max=M_max,
-                        metric=kform)
-                    tape.log(step_row(
-                        res, ins_mask, expand_width,
-                        nb + rev_checks, nc + rev_checks))
+                # ONE dispatch for search + mPrune + commit of this layer's
+                # inserts (DESIGN.md §12).
+                nl, nd, row, got, cache_d, cache_has = build_lib.insert_batch(
+                    lids[layer], ldist[layer], data, u, ins_mask, queries,
+                    efc, M, alpha1, entry, cache_d, cache_has, **step_kw)
+                tape.log(row)
                 next_entry = jnp.where(
                     ins_mask[:, None] & (got != INVALID), got, next_entry)
                 lids = lids.at[layer].set(nl)

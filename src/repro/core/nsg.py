@@ -10,11 +10,10 @@ Parameters per graph: (K_i initial out-degree, L_i pool, M_i degree limit).
 The exact KNNG is computed once at K_max and every graph takes a prefix —
 the deterministic shared-initialization strategy (counted once under ESO).
 
-``build_impl`` (DESIGN.md §12): "fused" collapses each batch's
-search + KNNG-row candidate merge + mPrune + commit into one
-``core/build.nsg_insert_batch`` dispatch; "per_batch" keeps the
-host-driven stages.  Both accumulate counters on device (CounterTape)
-and sync once at the end of the main pass.
+Each batch's search + KNNG-row candidate merge + mPrune + commit is one
+``core/build.nsg_insert_batch`` dispatch (DESIGN.md §12).  Counters
+accumulate on device (CounterTape) and sync once at the end of the main
+pass.
 """
 from __future__ import annotations
 
@@ -24,9 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import build as build_lib
-from repro.core import commit, graph, knng, prune, search
+from repro.core import graph, knng, search
 from repro.core import metric as metric_lib
-from repro.core.counters import BuildCounters, CounterTape, step_row
+from repro.core.counters import BuildCounters, CounterTape
 from repro.core.graph import INVALID, MultiGraph
 from repro.kernels import ops
 
@@ -65,9 +64,7 @@ def build_multi_nsg(
     metric: str = "l2",
     visited_impl: str = "dense",
     expand_width: int = 1,
-    build_impl: str = "per_batch",
 ) -> NSGBuildResult:
-    build_impl = build_lib.resolve_build_impl(build_impl)
     del seed
     met = metric_lib.resolve(metric)
     data = met.prepare(data)      # normalize ONCE for cosine (no-op otherwise)
@@ -109,62 +106,17 @@ def build_multi_nsg(
         queries = data[jnp.minimum(u, n - 1)]
         entry = jnp.broadcast_to(jnp.int32(ep), (b, m))
 
-        if build_impl == "fused":
-            # ONE dispatch: search + KNNG-row merge + mPrune + commit
-            # (DESIGN.md §12).  Same statements as below, traced.
-            new_ids, new_dist, row = build_lib.nsg_insert_batch(
-                init_stack, g.ids, g.dist, knn_ids, knn_dist, data, u,
-                row_mask, queries, L, M, alpha1, K, entry,
-                ef_max=L_max, max_hops=hops, share_cache=use_eso,
-                use_epo=use_epo, metric=kform, visited_impl=visited_impl,
-                expand_width=expand_width, k_in=k_in, m_max=M_max,
-                k_max=K_max)
-            g = MultiGraph(ids=new_ids, dist=new_dist)
-            tape.log(row)
-            continue
-
-        res = search.beam_search(
-            init_stack, data, queries, jnp.where(row_mask, u, INVALID),
-            row_mask, L, entry, ef_max=L_max, max_hops=hops,
-            share_cache=use_eso, metric=kform, visited_impl=visited_impl,
-            expand_width=expand_width)
-
-        # NSG's prune candidates are the nodes *visited* during search; the
-        # pool alone loses u's local KNNG structure.  Merge each node's own
-        # KNNG row (exact distances already known — no extra #dist) with the
-        # search pool, dedup, and sort ascending (prune-order requirement).
-        u_safe = jnp.minimum(u, n - 1)
-        own_ids = jnp.broadcast_to(knn_ids[u_safe][None],
-                                   (m,) + knn_ids[u_safe].shape)
-        own_dist = jnp.broadcast_to(knn_dist[u_safe][None], own_ids.shape)
-        kmask = (jnp.arange(K_max)[None, None, :] < K[:, None, None])
-        own_ids = jnp.where(kmask & row_mask[None, :, None], own_ids, INVALID)
-        own_dist = jnp.where(own_ids != INVALID, own_dist, jnp.inf)
-        cand_ids = jnp.concatenate(
-            [jnp.transpose(res.pool_ids, (1, 0, 2)), own_ids], axis=-1)
-        cand_dist = jnp.concatenate(
-            [jnp.transpose(res.pool_dist, (1, 0, 2)), own_dist], axis=-1)
-        # dedup ids (keep first occurrence after sort-by-distance)
-        srt = jnp.argsort(cand_dist, axis=-1)
-        cand_ids = jnp.take_along_axis(cand_ids, srt, axis=-1)
-        cand_dist = jnp.take_along_axis(cand_dist, srt, axis=-1)
-        eq = cand_ids[:, :, None, :] == cand_ids[:, :, :, None]
-        c = cand_ids.shape[-1]
-        tri = jnp.tril(jnp.ones((c, c), bool), k=-1)
-        dup = jnp.any(eq & tri[None, None], axis=-1)
-        cand_ids = jnp.where(dup, INVALID, cand_ids)
-        cand_dist = jnp.where(dup, jnp.inf, cand_dist)
-        valid = cand_ids != INVALID
-        pruned, nb, nc = prune.multi_prune(
-            data, cand_ids, cand_dist, valid, M, alpha1,
-            m_max=M_max, use_epo=use_epo, metric=kform)
-
-        new_ids, new_dist, rev_checks = commit.commit_group(
-            data, g.ids, g.dist, u, pruned, row_mask, M, alpha1,
-            k_in=k_in, m_max=M_max, metric=kform)
+        # ONE dispatch: search + KNNG-row merge + mPrune + commit
+        # (DESIGN.md §12).
+        new_ids, new_dist, row = build_lib.nsg_insert_batch(
+            init_stack, g.ids, g.dist, knn_ids, knn_dist, data, u,
+            row_mask, queries, L, M, alpha1, K, entry,
+            ef_max=L_max, max_hops=hops, share_cache=use_eso,
+            use_epo=use_epo, metric=kform, visited_impl=visited_impl,
+            expand_width=expand_width, k_in=k_in, m_max=M_max,
+            k_max=K_max)
         g = MultiGraph(ids=new_ids, dist=new_dist)
-        tape.log(step_row(res, row_mask, expand_width,
-                          nb + rev_checks, nc + rev_checks))
+        tape.log(row)
 
     tape.drain_into(ctr)          # the main pass's ONE counter host sync
 

@@ -15,6 +15,7 @@ from typing import Any
 
 import numpy as np
 
+from repro.core import build as build_lib
 from repro.core import eval as evallib
 from repro.core import hnsw as hnswlib
 from repro.core import metric as metric_lib
@@ -100,7 +101,7 @@ def estimate(
     metric: str = "l2",
     visited_impl: str = "dense",
     expand_width: int = 1,
-    build_impl: str = "per_batch",
+    build_impl: str = "fused",
 ) -> EstimationRecord:
     """Estimate the quality of each configuration in ``cfgs``.
 
@@ -113,11 +114,13 @@ def estimate(
     applies to both (DESIGN.md §10); the default 1 keeps estimation
     paper-exact, while W > 1 estimates with the multi-expansion schedule
     serving will actually run (and speeds the measured QPS sweeps up).
-    ``build_impl="fused"`` runs each group's build with single-dispatch
-    batch steps — same graphs, same counters, less dispatch overhead
-    (DESIGN.md §12).  Each call also appends its counters and the
-    compilations it caused to ``obs.ESTIMATES``.
+    Each group's build runs its batch steps as one compiled dispatch
+    (DESIGN.md §12).  ``build_impl`` accepts only "fused" and is not passed
+    on: it stays only until the benchmark's mixes stop naming it.  Each call
+    also appends its counters and the compilations it caused to
+    ``obs.ESTIMATES``.
     """
+    build_lib.resolve_build_impl(build_impl)
     ef_grid = resolve_ef_grid(k, ef_grid)
     # Prepare the data ONCE and hand the kernel form down: otherwise every
     # timed cosine search renormalizes the full (n, d) matrix in-jit,
@@ -145,8 +148,7 @@ def estimate(
                 use_eso=use_eso and len(group) > 1,
                 use_epo=use_epo and len(group) > 1,
                 batch_size=build_batch_size, metric=metric,
-                visited_impl=visited_impl, expand_width=expand_width,
-                build_impl=build_impl)
+                visited_impl=visited_impl, expand_width=expand_width)
             t_build += time.perf_counter() - t0
             ctr = ctr.add(res.counters)
             t0 = time.perf_counter()

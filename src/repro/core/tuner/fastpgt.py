@@ -87,7 +87,6 @@ def tune(
     metric: str = "l2",
     visited_impl: str = "dense",
     expand_width: int = 1,
-    build_impl: str = "per_batch",
 ) -> TuneResult:
     from repro.core import eval as evallib   # local: avoids cycles
 
@@ -123,7 +122,7 @@ def tune(
                 group_size=group_size, use_eso=eso, use_epo=epo, seed=seed,
                 build_batch_size=build_batch_size, timing_reps=timing_reps,
                 metric=metric, visited_impl=visited_impl,
-                expand_width=expand_width, build_impl=build_impl)
+                expand_width=expand_width)
             t_est += time.perf_counter() - t0
         ctr = ctr.add(rec.counters)
         n_dist_eval += rec.n_dist_eval

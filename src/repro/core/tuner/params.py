@@ -105,20 +105,17 @@ def to_build_params(pg: str, cfg: dict[str, Any]):
 def build_many(pg: str, data, build_params: list, *, seed: int,
                use_eso: bool, use_epo: bool, batch_size: int,
                metric: str = "l2", visited_impl: str = "dense",
-               expand_width: int = 1, build_impl: str = "per_batch"):
+               expand_width: int = 1):
     """Dispatch to the multi-builders. Returns the per-PG BuildResult.
 
     ``expand_width`` defaults to 1: construction follows the paper's
     sequential best-first schedule so §2.1 bit-identity and the paper-exact
-    #dist counters hold (DESIGN.md §10).  ``build_impl`` selects the batch
-    execution strategy — "fused" runs each batch step (Vamana: the whole
-    pass) as one compiled dispatch; graphs and counters match per_batch up
-    to a documented ppm-level FP-tie deviation (DESIGN.md §12).
+    #dist counters hold (DESIGN.md §10).  Every builder runs its batch step
+    as one compiled dispatch (Vamana: the whole pass; DESIGN.md §12).
     """
     kw = dict(seed=seed, use_eso=use_eso, use_epo=use_epo,
               batch_size=batch_size, metric=metric,
-              visited_impl=visited_impl, expand_width=expand_width,
-              build_impl=build_impl)
+              visited_impl=visited_impl, expand_width=expand_width)
     if pg == "hnsw":
         return hnswlib.build_multi_hnsw(data, build_params, **kw)
     if pg == "vamana":

@@ -14,15 +14,10 @@ default to "dense" so graph outputs and #dist counters stay bit-identical
 to the paper's accounting (DESIGN.md §2.1, §9) — "hash" trades exact
 counters for O(ef)-memory search state.
 
-``build_impl`` selects the batch-step execution strategy (DESIGN.md §12):
-"per_batch" drives each batch from the host (one search dispatch + m prune
-+ m commit dispatches per batch), "fused" runs the whole insertion pass as
-ONE device-resident compiled dispatch (``core/build.fused_vamana_pass``).
-Graphs and counters are bit-identical at test scale; at large n the fused
-path deviates from the staged path at the ppm level because per_batch's
-eager prune-stage distance reduction accumulates in a different order than
-the compiled step (documented + bounded, DESIGN.md §12).  Both paths
-accumulate counters on device and sync to the host once at the end.
+The main pass is ONE device-resident compiled dispatch
+(``core/build.fused_vamana_pass``, DESIGN.md §12): a ``lax.fori_loop`` over
+every insertion batch, with per-batch counter rows logged on device and
+synced to the host once at the end.
 """
 from __future__ import annotations
 
@@ -32,9 +27,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import build as build_lib
-from repro.core import commit, graph, obs, prune, search
+from repro.core import graph, obs, search
 from repro.core import metric as metric_lib
-from repro.core.counters import BuildCounters, CounterTape, step_row
+from repro.core.counters import BuildCounters, CounterTape
 from repro.core.graph import INVALID, MultiGraph
 
 
@@ -70,9 +65,7 @@ def build_multi_vamana(
     metric: str = "l2",
     visited_impl: str = "dense",
     expand_width: int = 1,
-    build_impl: str = "per_batch",
 ) -> BuildResult:
-    build_impl = build_lib.resolve_build_impl(build_impl)
     met = metric_lib.resolve(metric)
     data = met.prepare(data)      # normalize ONCE for cosine (no-op otherwise)
     kform = met.kernel            # hot loops see only the kernel form
@@ -114,44 +107,14 @@ def build_multi_vamana(
                    expand_width=expand_width, k_in=k_in, m_max=M_max)
 
     # ---- main pass (Alg. 6 l.4-12), batched ---------------------------------
-    if build_impl == "fused":
-        # ONE compiled dispatch for the whole pass: lax.fori_loop over
-        # batches on device, counter rows logged into a device array
-        # (DESIGN.md §12).  Bit-identical to the per_batch loop below.
-        new_ids, new_dist, log = build_lib.fused_vamana_pass(
-            g.ids, g.dist, data, L, M, alpha, jnp.int32(ep),
-            batch_size=batch_size, **step_kw)
-        g = MultiGraph(ids=new_ids, dist=new_dist)
-        tape.log_many(log)
-    else:
-        for off in range(0, n, batch_size):
-            ids_np = np.arange(off, min(off + batch_size, n), dtype=np.int32)
-            b = batch_size
-            u = jnp.full((b,), n, jnp.int32).at[:len(ids_np)].set(ids_np)
-            row_mask = jnp.arange(b) < len(ids_np)
-            queries = data[jnp.minimum(u, n - 1)]
-            entry = jnp.broadcast_to(jnp.int32(ep), (b, m))
-
-            res = search.beam_search(
-                g.ids, data, queries, jnp.where(row_mask, u, INVALID),
-                row_mask, L, entry, ef_max=L_max, max_hops=hops,
-                share_cache=use_eso, metric=kform,
-                visited_impl=visited_impl, expand_width=expand_width)
-
-            cand_ids = jnp.transpose(res.pool_ids, (1, 0, 2))  # (m, b, L_max)
-            cand_dist = jnp.transpose(res.pool_dist, (1, 0, 2))
-            valid = cand_ids != INVALID
-            pruned, nb, nc = prune.multi_prune(
-                data, cand_ids, cand_dist, valid, M, alpha,
-                m_max=M_max, use_epo=use_epo, metric=kform)
-
-            new_ids, new_dist, rev_checks = commit.commit_group(
-                data, g.ids, g.dist, u, pruned, row_mask, M, alpha,
-                k_in=k_in, m_max=M_max, metric=kform)
-            g = MultiGraph(ids=new_ids, dist=new_dist)
-            # device-side accumulation: no host round-trip per batch
-            tape.log(step_row(res, row_mask, expand_width,
-                              nb + rev_checks, nc + rev_checks))
+    # ONE compiled dispatch for the whole pass: lax.fori_loop over batches on
+    # device, counter rows logged into a device array (DESIGN.md §12).  Looked
+    # up through the module at call time so a wrapper installed there applies.
+    new_ids, new_dist, log = build_lib.fused_vamana_pass(
+        g.ids, g.dist, data, L, M, alpha, jnp.int32(ep),
+        batch_size=batch_size, **step_kw)
+    g = MultiGraph(ids=new_ids, dist=new_dist)
+    tape.log_many(log)
 
     tape.drain_into(ctr)          # the build's ONE counter host sync
     g = MultiGraph(ids=g.ids[inv_order], dist=g.dist[inv_order])

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.core import build as build_lib
 from repro.core import graph as graph_lib
 from repro.core import metric as metric_lib
 from repro.models import model as M
@@ -42,10 +41,6 @@ class RetrievalKnobs:
     visited_impl: "hash" = O(ef) search state for any context length;
                   "dense" = exact-#dist instrumentation (DESIGN.md §9).
     block_size:   queries per compiled search shape on the batched path.
-    build_impl:   index-construction execution strategy (DESIGN.md §12) —
-                  "fused" builds with single-dispatch batch steps
-                  (same graphs up to documented ppm-level FP ties, lower
-                  host overhead), "per_batch" keeps the host-driven stages.
     num_shards:   corpus partitions (DESIGN.md §11) — a *build-time* knob
                   consumed by ``retrieval.build_index``: > 1 splits the
                   keys over a "shard" mesh axis so no device holds the
@@ -91,7 +86,6 @@ class RetrievalKnobs:
     expand_width: int = retrieval_lib.DEFAULT_EXPAND_WIDTH
     visited_impl: str = "hash"
     block_size: int = 64
-    build_impl: str = "per_batch"
     num_shards: int = 1
     assign: str = "chunked"
     routed_shards: int | None = None
@@ -137,7 +131,6 @@ class RetrievalKnobs:
                 f"quantize {self.quantize!r} not in "
                 f"{metric_lib.QUANTIZE_MODES} (DESIGN.md §16: 'none' = fp32 "
                 f"corpus, 'sq8' = int8 search + fp32 re-rank)")
-        build_lib.resolve_build_impl(self.build_impl)   # fail fast, not at build
 
     def search_kwargs(self) -> dict:
         """kwargs for ``retrieval.retrieval_attention`` (single batch)."""
@@ -152,8 +145,8 @@ class RetrievalKnobs:
 
     def index_kwargs(self) -> dict:
         """Build-time kwargs for ``retrieval.build_index``."""
-        return dict(num_shards=self.num_shards, build_impl=self.build_impl,
-                    assign=self.assign, quantize=self.quantize)
+        return dict(num_shards=self.num_shards, assign=self.assign,
+                    quantize=self.quantize)
 
 
 @dataclasses.dataclass

@@ -53,6 +53,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.core import build as build_lib
 from repro.core import graph as graph_lib
 from repro.core import metric as metric_lib
 from repro.core import search as search_lib
@@ -105,7 +106,7 @@ def build_index(keys: jax.Array, values: jax.Array,
                 params: vamana_lib.VamanaParams, *, metric: str = "ip",
                 seed: int = 0, batch_size: int = 256,
                 num_shards: int = 1, assign: str = "chunked",
-                build_impl: str = "per_batch",
+                build_impl: str = "fused",
                 quantize: str = "none") -> RetrievalIndex:
     """Index one head's keys under ``metric`` (default: native ip/MIPS).
 
@@ -122,10 +123,10 @@ def build_index(keys: jax.Array, values: jax.Array,
     the whole corpus.  The default 1 is bit-identical to the unsharded
     path — same builder call, same ``knn_search``.
 
-    ``build_impl="fused"`` runs the Vamana build's whole insertion pass as
-    one compiled dispatch (DESIGN.md §12) — same graphs up to documented
-    ppm-level FP ties, less host dispatch overhead while prefill indexes
-    are constructed.
+    The Vamana build runs its whole insertion pass as one compiled
+    dispatch (DESIGN.md §12).  ``build_impl`` accepts only "fused", is
+    recorded in ``provenance`` and is not passed on: it stays only until
+    the benchmark's mixes stop naming it.
 
     ``quantize="sq8"`` (DESIGN.md §16) additionally stores an int8 SQ8
     view of the prepared keys (scale computed ONCE here, carried on the
@@ -135,6 +136,7 @@ def build_index(keys: jax.Array, values: jax.Array,
     paper-exact #dist accounting holds — so "sq8" changes search-time
     representation only.
     """
+    build_lib.resolve_build_impl(build_impl)
     met = metric_lib.resolve(metric)
     if quantize not in metric_lib.QUANTIZE_MODES:
         raise ValueError(
@@ -146,8 +148,7 @@ def build_index(keys: jax.Array, values: jax.Array,
     if num_shards == 1:
         res = vamana_lib.build_vamana(search_keys, params, seed=seed,
                                       batch_size=batch_size,
-                                      metric=met.kernel,
-                                      build_impl=build_impl)
+                                      metric=met.kernel)
         quant = (metric_lib.quantize_sq8(search_keys)
                  if quantize == "sq8" else None)
         return RetrievalIndex(graph_ids=res.g.ids[0], keys=keys,
@@ -159,8 +160,7 @@ def build_index(keys: jax.Array, values: jax.Array,
     def shard_builder(local):
         res = vamana_lib.build_vamana(local, params, seed=seed,
                                       batch_size=batch_size,
-                                      metric=met.kernel,
-                                      build_impl=build_impl)
+                                      metric=met.kernel)
         return res.g.ids[0], res.entry
 
     shards = graph_lib.partition(search_keys, num_shards,

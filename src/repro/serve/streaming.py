@@ -39,8 +39,8 @@ corpus needs:
 
   4. **Background compaction.**  ``maybe_compact`` (and a full delta at
      insert time) rebuilds the affected shards off the search path —
-     shards owning tombstones or receiving delta vectors are rebuilt with
-     ``build_impl="fused"`` (DESIGN.md §12); untouched shards keep their
+     shards owning tombstones or receiving delta vectors are rebuilt by
+     the compiled build step (DESIGN.md §12); untouched shards keep their
      graph arrays byte-for-byte and are restacked through
      ``graph.assemble_sharded`` — then hot-swaps into a running engine via
      ``ResilientSearcher.swap_index`` (which resets the latency governor;
@@ -433,7 +433,7 @@ class MutableIndex:
         params = self.main.params.clamped(n)
         res = vamana_lib.build_vamana(
             jnp.asarray(self._d_search[:n]), params,
-            metric=self._met.kernel, build_impl="fused")
+            metric=self._met.kernel)
         self._dg_ids = res.g.ids[0]
         self._dg_entry = int(res.entry)
         self._dg_n = n
@@ -776,7 +776,7 @@ class MutableIndex:
             self.main.params.clamped(int(local.shape[0])),
             seed=int(prov.get("seed", 0)),
             batch_size=int(prov.get("batch_size", 256)),
-            metric=self._met.kernel, build_impl="fused")
+            metric=self._met.kernel)
         return res.g.ids[0], res.entry
 
     # -- persistence --------------------------------------------------------

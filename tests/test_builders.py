@@ -57,8 +57,7 @@ def test_multi_equals_single_across_degree_buckets():
             np.asarray(single.g.ids[0])[:, :p.M])
 
 
-@pytest.mark.parametrize("build_impl", ["per_batch", "fused"])
-def test_entry_inserted_last_keeps_graph_connected(ds, build_impl):
+def test_entry_inserted_last_keeps_graph_connected(ds):
     """The entry (medoid) inserted in the last batch: its own insertion
     searches from itself, and that search must expand its neighbourhood.
     An empty pool there wiped the entry's out-edges, leaving only the
@@ -70,7 +69,7 @@ def test_entry_inserted_last_keeps_graph_connected(ds, build_impl):
     order = np.r_[np.delete(np.arange(n), ep), ep]
     x = data[order]
     res = vamana.build_vamana(x, vamana.VamanaParams(32, 12, 1.2),
-                              batch_size=batch, build_impl=build_impl)
+                              batch_size=batch)
     assert res.entry == n - 1
     out = np.asarray(res.g.ids[0])[res.entry]
     out = out[out >= 0]

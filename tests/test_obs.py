@@ -111,7 +111,7 @@ def tune_trace(tmp_path_factory):
         res = fastpgt.tune("vamana", data, queries, mode="fastpgt", budget=4,
                            batch=2, init_random=2, seed=0, scale=0.1,
                            build_batch_size=128, ef_grid=[10, 20],
-                           mc_samples=8, build_impl="fused")
+                           mc_samples=8)
     finally:
         jax.profiler.stop_trace()
     events = scope_report.load(scope_report.xplane_file(d),
@@ -143,8 +143,7 @@ def test_estimate_records_its_call():
     gt = evallib.ground_truth(data, queries, 10)
     cfgs = [{"L": 16, "M": 8, "alpha": 1.2}, {"L": 20, "M": 6, "alpha": 1.1}]
     rec = estimator.estimate("vamana", data, queries, gt, cfgs, group_size=2,
-                             ef_grid=[10], build_batch_size=128,
-                             build_impl="fused")
+                             ef_grid=[10], build_batch_size=128)
     last = obs.ESTIMATES[-1]
     assert last["configs"] == 2
     assert last["total"] == rec.counters.total
@@ -198,8 +197,7 @@ def cpu_report(tmp_path_factory):
           vamana.VamanaParams(L=24, M=8, alpha=1.3)]
 
     def work():
-        res = vamana.build_multi_vamana(data, ps, batch_size=128,
-                                        build_impl="fused")
+        res = vamana.build_multi_vamana(data, ps, batch_size=128)
         hits = search.knn_search(res.g.ids[0], data, data[:32], 10, 16,
                                  res.entry)
         jax.block_until_ready(hits.pool_ids)

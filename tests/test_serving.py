@@ -132,15 +132,16 @@ def test_sharded_index_serves_and_blocks_consistently():
 def test_retrieval_knobs_num_shards():
     from repro.serve.engine import RetrievalKnobs
     assert RetrievalKnobs().index_kwargs() == {
-        "num_shards": 1, "build_impl": "per_batch", "assign": "chunked",
-        "quantize": "none"}
-    assert RetrievalKnobs(num_shards=4, build_impl="fused").index_kwargs() == {
-        "num_shards": 4, "build_impl": "fused", "assign": "chunked",
-        "quantize": "none"}
+        "num_shards": 1, "assign": "chunked", "quantize": "none"}
+    assert RetrievalKnobs(num_shards=4).index_kwargs() == {
+        "num_shards": 4, "assign": "chunked", "quantize": "none"}
     with pytest.raises(ValueError, match="num_shards"):
         RetrievalKnobs(num_shards=0)
-    with pytest.raises(ValueError, match="build_impl"):
-        RetrievalKnobs(build_impl="nope")
+    # the build has one driver: the knob is gone (DESIGN.md §12)
+    assert "build_impl" not in {
+        f.name for f in dataclasses.fields(RetrievalKnobs)}
+    with pytest.raises(TypeError, match="build_impl"):
+        RetrievalKnobs(build_impl="fused")
 
 
 def test_retrieval_knobs_routing():
